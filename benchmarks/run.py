@@ -25,9 +25,12 @@ artifacts, not history.
 
 import argparse
 import json
+import os
 import platform
 import sys
 import time
+
+from repro.launch import use_compile_cache
 
 from . import (fig1_breakdown, fig2_threshold, fig4_membership,
                fig5_termfilter, fig6_pruning, fig7_streaming, fig8_serving,
@@ -64,6 +67,9 @@ def main() -> None:
     ap.add_argument("--json", metavar="PATH", default=None,
                     help="also write the rows to this JSON file")
     args = ap.parse_args()
+    use_compile_cache(os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        ".jax_cache"))
     unknown = [n for n in args.names if n not in SUITES]
     if unknown:
         ap.error(f"unknown suite(s): {', '.join(unknown)}")

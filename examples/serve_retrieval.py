@@ -41,7 +41,7 @@ def main(n_docs: int = 2048, n_centroids: int = 512,
     cfg = EngineConfig(k=10, n_filter=nf, n_docs=nd, th=0.2, th_r=0.3)
 
     print("sharding index across devices (local IVFs, two-level top-k) ...")
-    stacked = shard_index(index, n_dev)
+    stacked = shard_index(index, n_dev, mesh=mesh)
     retriever = make_shardmap_retriever(mesh, cfg)
 
     # device-resident queries ONCE, outside the loop: timing host numpy

@@ -31,9 +31,8 @@ phase 1b-2 sequence (bitpack -> bitfilter -> mask -> top_k, with full-corpus
 intermediates) by the single ``kernels/prefilter.py`` megakernel;
 ``fused_late_interaction`` does the same for phases 3-4 (cinter -> top_k ->
 gather -> pqscore -> top_k becomes the single ``kernels/pqinter.py``
-megakernel); ``kernel_interpret`` picks Pallas interpret mode (CPU) vs
-compiled Mosaic (TPU) — it replaces the old mutable ``kernels.ops.INTERPRET``
-module global.
+megakernel). Whether the kernels run in the Pallas interpreter or compile
+with Mosaic follows the platform (``repro.kernels.ops``), not the config.
 """
 from __future__ import annotations
 
@@ -84,8 +83,6 @@ class EngineConfig:
     # cinter -> top_k -> gather -> pqscore -> top_k with per-survivor
     # intermediates. False keeps the two separate kernels.
     fused_late_interaction: bool = True
-    # Pallas interpret mode (CPU validation) vs compiled Mosaic (TPU).
-    kernel_interpret: bool = True
     # With use_kernels + a fused megakernel: run each micro-batch as ONE
     # batch-native kernel launch (kernels/prefilter.py::prefilter_batched,
     # kernels/pqinter.py::pqinter_batched) that loads the index-resident
@@ -274,7 +271,7 @@ def _phase1(q: jax.Array, index: PackedIndex, cfg: EngineConfig,
     kops = _kops(cfg)
     cs = centroid_scores(q, index.centroids, cfg.cs_dtype)
     if kops is not None:
-        bits = kops.bitpack(cs, cfg.th, q_mask, interpret=cfg.kernel_interpret)
+        bits = kops.bitpack(cs, cfg.th, q_mask)
     else:
         bits = bitvector.build_bitvectors(cs, cfg.th, q_mask)
     probe_ids = bitvector.masked_topk_centroids(cs, cfg.th, cfg.nprobe,
@@ -304,8 +301,7 @@ def _phase2(index: PackedIndex, token_mask: jax.Array, bits: jax.Array,
         c_codes = jnp.take(index.codes, cand_ids, axis=0)
         c_mask = jnp.take(token_mask, cand_ids, axis=0) & cand_valid[:, None]
         if kops is not None:
-            f = kops.bitfilter(bits, c_codes, c_mask,
-                               interpret=cfg.kernel_interpret)
+            f = kops.bitfilter(bits, c_codes, c_mask)
         else:
             f = bitvector.filter_score(bits, c_codes, c_mask)
         f = jnp.where(cand_valid, f, -1)
@@ -313,8 +309,7 @@ def _phase2(index: PackedIndex, token_mask: jax.Array, bits: jax.Array,
         sel1 = jnp.take(cand_ids, sel1_local)
     else:
         if kops is not None:
-            f = kops.bitfilter(bits, index.codes, token_mask,
-                               interpret=cfg.kernel_interpret)
+            f = kops.bitfilter(bits, index.codes, token_mask)
         else:
             f = bitvector.filter_score(bits, index.codes, token_mask)
         f = jnp.where(bitmap, f, -1)                             # (n_docs,)
@@ -347,8 +342,7 @@ def _phase12(q: jax.Array, index: PackedIndex, token_mask: jax.Array,
         c_codes = jnp.take(index.codes, cand_ids, axis=0)
         c_mask = jnp.take(token_mask, cand_ids, axis=0)
         _, sel1_local, _ = kops.prefilter(cs, cfg.th, c_codes, c_mask,
-                                          cand_valid, cfg.n_filter, q_mask,
-                                          interpret=cfg.kernel_interpret)
+                                          cand_valid, cfg.n_filter, q_mask)
         sel1 = jnp.take(cand_ids, sel1_local)
     else:
         # score_all: the predicate words ride into the megakernel and the
@@ -357,8 +351,7 @@ def _phase12(q: jax.Array, index: PackedIndex, token_mask: jax.Array,
         plan = None if cfg.doc_filter is None else cfg.doc_filter.clauses
         _, sel1, _ = kops.prefilter(cs, cfg.th, index.codes, token_mask,
                                     bitmap, cfg.n_filter, q_mask,
-                                    pred_words=index.pred_words, plan=plan,
-                                    interpret=cfg.kernel_interpret)
+                                    pred_words=index.pred_words, plan=plan)
     return cs, sel1.astype(jnp.int32)
 
 
@@ -371,8 +364,7 @@ def _phase3(index: PackedIndex, token_mask: jax.Array, cs: jax.Array,
     s1_codes = jnp.take(index.codes, sel1, axis=0)               # (nf, cap)
     s1_mask = jnp.take(token_mask, sel1, axis=0)
     if kops is not None:
-        sbar = kops.cinter(cs_t, s1_codes, s1_mask, q_mask,
-                           interpret=cfg.kernel_interpret)
+        sbar = kops.cinter(cs_t, s1_codes, s1_mask, q_mask)
     else:
         sbar = interaction.centroid_interaction(cs_t, s1_codes, s1_mask,
                                                 q_mask)
@@ -401,7 +393,7 @@ def _phase4(index: PackedIndex, token_mask: jax.Array, q: jax.Array,
     s2_mask = jnp.take(token_mask, sel2, axis=0)
     if kops is not None:
         scores = kops.pqscore(cs_t, lut, s2_codes, s2_res, s2_mask, cfg.th_r,
-                              q_mask, interpret=cfg.kernel_interpret)
+                              q_mask)
     elif cfg.compact_cap is not None and cfg.th_r is not None:
         scores = interaction.late_interaction_pq_compact(
             cs_t, lut, s2_codes, s2_res, s2_mask, cfg.th_r, cfg.compact_cap,
@@ -448,7 +440,7 @@ def _phase34(index: PackedIndex, token_mask: jax.Array, q: jax.Array,
     s1_pass = None if doc_pass is None else jnp.take(doc_pass, sel1)
     top_scores, top_pos, _, _ = kops.pqinter(
         cs.T, lut, s1_codes, s1_res, s1_mask, cfg.th_r, cfg.n_docs, cfg.k,
-        q_mask, doc_pass=s1_pass, interpret=cfg.kernel_interpret)
+        q_mask, doc_pass=s1_pass)
     return top_scores, jnp.take(sel1, top_pos)
 
 
@@ -511,15 +503,13 @@ def _phase12_batch(index: PackedIndex, token_mask: jax.Array,
         c_codes = jnp.take(index.codes, cand_ids, axis=0)  # (B, cand_cap, cap)
         c_mask = jnp.take(token_mask, cand_ids, axis=0)
         _, sel1_local, _ = kops.prefilter_batched(
-            cs, cfg.th, c_codes, c_mask, cand_valid, cfg.n_filter, q_masks,
-            interpret=cfg.kernel_interpret)
+            cs, cfg.th, c_codes, c_mask, cand_valid, cfg.n_filter, q_masks)
         sel1 = jnp.take_along_axis(cand_ids, sel1_local, axis=1)
     else:
         plan = None if cfg.doc_filter is None else cfg.doc_filter.clauses
         _, sel1, _ = kops.prefilter_batched(
             cs, cfg.th, index.codes, token_mask, bitmap, cfg.n_filter,
-            q_masks, pred_words=index.pred_words, plan=plan,
-            interpret=cfg.kernel_interpret)
+            q_masks, pred_words=index.pred_words, plan=plan)
     return cs, sel1.astype(jnp.int32)
 
 
@@ -550,8 +540,7 @@ def _phase34_batch(index: PackedIndex, token_mask: jax.Array,
     s1_pass = None if doc_pass is None else jnp.take(doc_pass, sel1)  # (B,nf)
     top_scores, top_pos, _, _ = kops.pqinter_batched(
         jnp.swapaxes(cs, -1, -2), lut, s1_codes, s1_res, s1_mask, cfg.th_r,
-        cfg.n_docs, cfg.k, q_masks, doc_pass=s1_pass,
-        interpret=cfg.kernel_interpret)
+        cfg.n_docs, cfg.k, q_masks, doc_pass=s1_pass)
     return RetrievalResult(top_scores,
                            jnp.take_along_axis(sel1, top_pos, axis=1))
 

@@ -34,10 +34,24 @@ def train_residual_codec(residuals: jax.Array, b: int) -> ResidualCodec:
     return ResidualCodec(cutoffs, bucket_weights, b)
 
 
+_ENCODE_ROWS = 1 << 16
+
+
 def encode_residual(r: jax.Array, codec: ResidualCodec) -> jax.Array:
-    """(..., d) -> (..., d * b / 8) uint8, bit-packed."""
-    codes = jnp.searchsorted(codec.cutoffs, r).astype(jnp.uint8)  # (..., d)
-    return pack_codes(codes, codec.b)
+    """(..., d) -> (..., d * b / 8) uint8, bit-packed.
+
+    Encodes ``_ENCODE_ROWS`` rows at a time: unchunked, ``searchsorted``
+    over a whole index build's residuals (5.2M x 128 at 65,536 MS MARCO
+    passages) needs ~10 GiB of TPU temporaries, more than a v5e chip has
+    left once the build's other arrays are resident."""
+    *lead, d = r.shape
+
+    def _rows(x):
+        codes = jnp.searchsorted(codec.cutoffs, x).astype(jnp.uint8)
+        return pack_codes(codes, codec.b)
+
+    packed = jax.lax.map(_rows, r.reshape(-1, d), batch_size=_ENCODE_ROWS)
+    return packed.reshape(*lead, packed.shape[-1])
 
 
 def decode_residual(packed: jax.Array, codec: ResidualCodec, d: int) -> jax.Array:

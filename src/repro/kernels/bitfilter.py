@@ -43,7 +43,7 @@ def _bitfilter_kernel(bits_ref, codes_ref, mask_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def bitfilter(bits: jax.Array, codes: jax.Array, token_mask: jax.Array, *,
-              block_d: int = DEFAULT_BD, interpret: bool = True) -> jax.Array:
+              block_d: int = DEFAULT_BD, interpret: bool) -> jax.Array:
     """bits (n_c,) u32; codes/token_mask (n_docs, cap) -> (n_docs,) int32."""
     n_docs, cap = codes.shape
     pad = (-n_docs) % block_d

@@ -31,7 +31,7 @@ def _bitpack_kernel(th_ref, cs_ref, qm_ref, out_ref):
 
 @functools.partial(jax.jit, static_argnames=("block_c", "interpret"))
 def bitpack(cs: jax.Array, th, q_mask: jax.Array | None = None, *,
-            block_c: int = DEFAULT_BC, interpret: bool = True) -> jax.Array:
+            block_c: int = DEFAULT_BC, interpret: bool) -> jax.Array:
     """cs (n_q, n_c) fp32, th scalar -> (n_c,) uint32.
 
     q_mask optional (n_q,) bool: masked (padded / pruned) query terms pack a

@@ -85,7 +85,7 @@ def _cinter_kernel(cs_t_ref, codes_ref, mask_ref, qm_ref, out_ref):
 @functools.partial(jax.jit, static_argnames=("block_d", "interpret"))
 def cinter(cs_t: jax.Array, codes: jax.Array, token_mask: jax.Array,
            q_mask: jax.Array | None = None, *,
-           block_d: int = DEFAULT_BD, interpret: bool = True) -> jax.Array:
+           block_d: int = DEFAULT_BD, interpret: bool) -> jax.Array:
     """cs_t (n_c, n_q); codes/token_mask (docs, cap) -> (docs,) fp32.
     q_mask optional (n_q,) bool — masked terms are excluded from S̄."""
     n_docs, cap = codes.shape

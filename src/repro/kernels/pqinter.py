@@ -64,7 +64,9 @@ array reads into (BD, cap) VMEM tiles behind double-buffered DMA and
 replace the merge ``lax.top_k`` with a bitonic merge over the 8x128 lanes;
 the row gather by phase-3 winner position is the one dynamic-DMA op without
 an unfused analogue. Everything else is VPU gather/compare/select, same as
-the unfused ``cinter``/``pqscore`` kernels.
+the unfused ``cinter``/``pqscore`` kernels. Mosaic refuses the kernel as
+written: its CS^T and LUT gathers are not 2D ("Only 2D gather is
+supported"), so today it runs only in interpret mode.
 """
 
 from __future__ import annotations
@@ -162,8 +164,8 @@ def pqinter(cs_t: jax.Array, lut: jax.Array, codes: jax.Array,
             q_mask: jax.Array | None = None, *,
             doc_pass: jax.Array | None = None,
             block_d1: int | None = None, block_d2: int | None = None,
-            interpret: bool = True) -> tuple[jax.Array, jax.Array,
-                                             jax.Array, jax.Array]:
+            interpret: bool) -> tuple[jax.Array, jax.Array,
+                                      jax.Array, jax.Array]:
     """Fused phases 3-4 for one query, over the phase-2 survivor set.
 
     cs_t       : (n_c, n_q) centroid scores, transposed (fp32 or bf16)
@@ -326,8 +328,8 @@ def pqinter_batched(cs_t: jax.Array, lut: jax.Array, codes: jax.Array,
                     doc_pass: jax.Array | None = None,
                     block_b: int | None = None, block_d1: int | None = None,
                     block_d2: int | None = None,
-                    interpret: bool = True) -> tuple[jax.Array, jax.Array,
-                                                     jax.Array, jax.Array]:
+                    interpret: bool) -> tuple[jax.Array, jax.Array,
+                                              jax.Array, jax.Array]:
     """Batch-native fused phases 3-4: one launch for a whole micro-batch.
 
     cs_t       : (B, n_c, n_q) per-query transposed centroid scores

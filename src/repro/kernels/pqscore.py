@@ -127,7 +127,7 @@ def _pqscore_kernel(cs_t_ref, lut2_ref, codes_ref, res_ref, mask_ref, thr_ref,
 def pqscore(cs_t: jax.Array, lut: jax.Array, codes: jax.Array,
             res_codes: jax.Array, token_mask: jax.Array,
             th_r: float | None, q_mask: jax.Array | None = None, *,
-            block_d: int = DEFAULT_BD, interpret: bool = True) -> jax.Array:
+            block_d: int = DEFAULT_BD, interpret: bool) -> jax.Array:
     """cs_t (n_c, n_q); lut (n_q, m, K); codes (docs, cap);
     res_codes (docs, cap, m) uint8 -> (docs,) fp32 final scores.
     q_mask optional (n_q,) bool — masked terms contribute nothing."""

@@ -40,8 +40,10 @@ TPU notes: the grid is sequential, so the step-0 bit table and the running
 keys live in revisited output blocks (the standard Pallas accumulator
 pattern).  The merge's ``lax.top_k`` is the one op a Mosaic build would
 replace with a bitonic merge over the 8x128 lanes; everything else is VPU
-compare/shift/gather, same as the unfused kernels.  Interpret mode (CPU) is
-the tier-1 validation target.
+compare/shift/gather, same as the unfused kernels.  Mosaic refuses the
+kernel as written (the uint32 ``jnp.sum`` of the bit pack, then the
+in-kernel ``jnp.take`` from the bit table), so today it runs only in
+interpret mode.
 """
 from __future__ import annotations
 
@@ -165,8 +167,8 @@ def prefilter_batched(cs: jax.Array, th, codes: jax.Array,
                       n_filter: int, q_masks: jax.Array | None = None, *,
                       pred_words: jax.Array | None = None, plan=None,
                       block_d: int = DEFAULT_BD,
-                      interpret: bool = True) -> tuple[jax.Array, jax.Array,
-                                                       jax.Array]:
+                      interpret: bool) -> tuple[jax.Array, jax.Array,
+                                                jax.Array]:
     """Batch-native fused phases 1b-2: one launch for a whole micro-batch.
 
     cs         : (B, n_q, n_c) centroid scores per query (fp32 or bf16)
@@ -260,8 +262,8 @@ def prefilter(cs: jax.Array, th, codes: jax.Array, token_mask: jax.Array,
               q_mask: jax.Array | None = None, *,
               pred_words: jax.Array | None = None, plan=None,
               block_d: int = DEFAULT_BD,
-              interpret: bool = True) -> tuple[jax.Array, jax.Array,
-                                               jax.Array]:
+              interpret: bool) -> tuple[jax.Array, jax.Array,
+                                        jax.Array]:
     """Fused phases 1b-2 for one query.
 
     cs         : (n_q, n_c) centroid scores (fp32 or bf16), n_q <= 32

@@ -1,16 +1,9 @@
-"""Distributed EMVB serving.
+"""Distributed EMVB serving: the shard_map plan.
 
-Two retrieval execution plans over the production mesh (DESIGN.md §4):
-
-  * ``retrieve_pjit``    — GSPMD/global-semantics: the engine runs on global
-    arrays, XLA inserts collectives. Baseline in EXPERIMENTS.md §Perf.
-  * ``retrieve_shardmap``— explicit plan: each device owns a doc shard with a
-    *local* IVF, runs the full four-phase pipeline locally for the whole
-    query batch, and the per-shard top-k are merged with one all-gather +
-    re-top-k (two-level top-k). This is the production plan: collective
-    traffic is O(B * k) instead of O(corpus gathers).
-
-Both run on any mesh size (tests use 1 device; the dry-run uses 512).
+Each device owns a doc shard with a *local* IVF, runs the full four-phase
+pipeline locally for the whole query batch, and the per-shard top-k are
+merged with one all-gather + re-top-k (two-level top-k): collective traffic
+is O(B * k) instead of O(corpus gathers). Runs on any mesh size.
 """
 from __future__ import annotations
 
@@ -19,36 +12,13 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import Mesh, PartitionSpec as P
+from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.core.engine import (EngineConfig, RetrievalResult,
                                _as_query_batch, _retrieve_batch,
                                _with_filter)
 from repro.core.index import PackedIndex
 from repro.obs import trace
-
-# jax >= 0.6 exposes shard_map at top level (replication check kw:
-# check_vma); 0.4.x has it under experimental (kw: check_rep).
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SM_KW = {"check_vma": False}
-else:  # pragma: no cover - exercised on jax 0.4.x containers
-    from jax.experimental.shard_map import shard_map as _shard_map
-    _SM_KW = {"check_rep": False}
-
-
-def _axis_size(ax: str):
-    if hasattr(jax.lax, "axis_size"):
-        return jax.lax.axis_size(ax)
-    return jax.lax.psum(1, ax)  # jax 0.4.x
-
-
-def retrieve_pjit(mesh: Mesh, index: PackedIndex, queries: jax.Array,
-                  cfg: EngineConfig) -> RetrievalResult:
-    """Global-semantics retrieval (GSPMD chooses the collectives)."""
-    from repro.core.engine import retrieve
-    with mesh:
-        return retrieve(index, queries, cfg)
 
 
 # ---------------------------------------------------------------------------
@@ -67,10 +37,8 @@ def _local_retrieve(index_local: PackedIndex, queries: jax.Array,
 
     # translate local doc ids -> global ids with the shard offset
     shard_id = jnp.int32(0)
-    n_shards = 1
     for ax in axes:
-        shard_id = shard_id * _axis_size(ax) + jax.lax.axis_index(ax)
-        n_shards *= _axis_size(ax)
+        shard_id = shard_id * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     n_local = index_local.codes.shape[0]
     global_ids = local.doc_ids + shard_id * n_local
 
@@ -105,10 +73,6 @@ def make_shardmap_retriever(mesh: Mesh, cfg: EngineConfig):
     program is traced on first unfiltered call, exactly as before).
     """
     axes = tuple(mesh.axis_names)
-    n_shards = 1
-    for a in axes:
-        n_shards *= mesh.shape[a]
-
     in_specs = (jax.tree.map(lambda _: P(axes), _index_struct()),
                 P(*([None])), P(*([None])))
     out_specs = RetrievalResult(P(None), P(None))
@@ -118,8 +82,8 @@ def make_shardmap_retriever(mesh: Mesh, cfg: EngineConfig):
     def _step_for(fcfg: EngineConfig):
         if fcfg not in steps:
             @functools.partial(jax.jit)
-            @functools.partial(_shard_map, mesh=mesh, in_specs=in_specs,
-                               out_specs=out_specs, **_SM_KW)
+            @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
+                               out_specs=out_specs, check_vma=False)
             def step(index_stacked, queries, q_masks):
                 index_local = jax.tree.map(lambda x: x[0], index_stacked)
                 return _local_retrieve(index_local, queries, q_masks,
@@ -177,9 +141,7 @@ def make_timeline_partial_plans(mesh: Mesh, cfg: EngineConfig, timeline, *,
     """
     from repro.core.engine import adapt_config_to_corpus
 
-    n_shards = 1
-    for a in mesh.axis_names:
-        n_shards *= mesh.shape[a]
+    n_shards = mesh.size
     fps = timeline.fingerprints if shard_cache is not None else None
     # one retriever per DISTINCT clamped config: equal-size generations (the
     # steady-state stream) share a single traced/compiled shard_map program
@@ -191,12 +153,12 @@ def make_timeline_partial_plans(mesh: Mesh, cfg: EngineConfig, timeline, *,
         if gcfg not in retrievers:
             retrievers[gcfg] = make_shardmap_retriever(mesh, gcfg)
         if shard_cache is None:
-            stacked = shard_index(gen, n_shards)
+            stacked = shard_index(gen, n_shards, mesh=mesh)
         else:
             ckey = (fps[g], n_shards)
             stacked = shard_cache.pop(ckey, None)
             if stacked is None:
-                stacked = shard_index(gen, n_shards)
+                stacked = shard_index(gen, n_shards, mesh=mesh)
             shard_cache[ckey] = stacked   # (re)insert at LRU tail
 
         def plan(queries, q_masks=None, doc_filter=None, *, _stacked=stacked,
@@ -263,13 +225,19 @@ def make_service(mesh: Mesh, cfg: EngineConfig, timeline, **service_kwargs):
         **service_kwargs)
 
 
-def shard_index(index: PackedIndex, n_shards: int) -> PackedIndex:
+def shard_index(index: PackedIndex, n_shards: int, *,
+                mesh: Mesh | None = None) -> PackedIndex:
     """Split a global index into per-shard local indices, stacked on a new
     leading axis. Docs are block-partitioned; each shard's IVF is rebuilt
     with local doc ids. (Host-side, numpy.) If a rebuilt local list exceeds
     the global list_cap a warning reports how many doc-id entries were
     dropped (those docs become unreachable through that centroid on that
-    shard)."""
+    shard).
+
+    With ``mesh`` (of ``n_shards`` devices) every stacked leaf is placed
+    with the shard_map plan's ``NamedSharding``, so device s holds shard s
+    and the plan moves no index bytes; without it the leaves go to the
+    default device."""
     import warnings
 
     import numpy as np
@@ -318,17 +286,28 @@ def shard_index(index: PackedIndex, n_shards: int) -> PackedIndex:
     def rep(x):
         return np.broadcast_to(np.asarray(x), (n_shards, *np.shape(x))).copy()
 
+    if mesh is None:
+        place = jnp.asarray
+    else:
+        if mesh.size != n_shards:
+            raise ValueError(f"mesh has {mesh.size} devices but n_shards="
+                             f"{n_shards}: one shard per device")
+        sharding = NamedSharding(mesh, P(tuple(mesh.axis_names)))
+
+        def place(x):
+            return jax.device_put(x, sharding)
+
     return PackedIndex(
-        centroids=jnp.asarray(rep(index.centroids)),
-        codes=jnp.asarray(codes),
-        doc_lens=jnp.asarray(doc_lens),
-        res_codes=jnp.asarray(res_codes),
-        pq_codebooks=jnp.asarray(rep(index.pq_codebooks)),
-        ivf=jnp.asarray(local_ivf),
-        ivf_lens=jnp.asarray(local_lens),
-        plaid_res=jnp.asarray(plaid_res),
-        plaid_cutoffs=jnp.asarray(rep(index.plaid_cutoffs)),
-        plaid_weights=jnp.asarray(rep(index.plaid_weights)),
-        opq_rotation=jnp.asarray(rep(index.opq_rotation)),
-        pred_words=jnp.asarray(pred_words),
+        centroids=place(rep(index.centroids)),
+        codes=place(codes),
+        doc_lens=place(doc_lens),
+        res_codes=place(res_codes),
+        pq_codebooks=place(rep(index.pq_codebooks)),
+        ivf=place(local_ivf),
+        ivf_lens=place(local_lens),
+        plaid_res=place(plaid_res),
+        plaid_cutoffs=place(rep(index.plaid_cutoffs)),
+        plaid_weights=place(rep(index.plaid_weights)),
+        opq_rotation=place(rep(index.opq_rotation)),
+        pred_words=place(pred_words),
     )

@@ -49,7 +49,7 @@ def findex(fcorpus):
 # lossless budgets: every phase keeps the whole corpus, so the filtered and
 # post-filtered rankings must agree bit for bit
 BASE = dict(n_q=8, nprobe=4, th=0.2, th_r=0.3, n_filter=N_DOCS,
-            n_docs=N_DOCS, k=8, cand_cap=N_DOCS, kernel_interpret=True)
+            n_docs=N_DOCS, k=8, cand_cap=N_DOCS)
 
 MODES = {
     "ref-score_all": {},

@@ -344,7 +344,7 @@ def test_pqinter_fused_block_boundaries():
         cs, codes, mask, lut, res = _inputs(32, 256, n_docs, 16, 8, 16,
                                             seed=7)
         out = pqinter(cs.T, lut, codes, res, mask, 0.3, nd, 9,
-                      block_d1=32, block_d2=16)
+                      block_d1=32, block_d2=16, interpret=True)
         exp = ref.pqinter(cs.T, lut, codes, res, mask, 0.3, nd, 9)
         for got, want, name in zip(out, exp, ("scores", "pos", "sel2",
                                               "sbar")):

@@ -4,6 +4,7 @@ multi-generation timeline plan equals the single-device merge path."""
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
 from repro.core import EngineConfig, engine
 from repro.launch.serve import (make_shardmap_retriever,
@@ -70,6 +71,20 @@ def test_shard_index_partitions_consistently(small_index):
         assert local_docs <= global_docs
         if sum(l_lens[s, c] for s in range(n_shards)) == len(global_docs):
             assert local_docs == global_docs
+
+
+def test_shard_index_places_shards_on_the_mesh(small_index):
+    """With a mesh, every stacked leaf carries the plan's NamedSharding
+    (shard s on device s), so the plan moves no index bytes per call."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    idx, _ = small_index
+    mesh = jax.make_mesh((1,), ("shard",))
+    want = NamedSharding(mesh, P(("shard",)))
+    for leaf in shard_index(idx, 1, mesh=mesh):
+        assert leaf.sharding.is_equivalent_to(want, leaf.ndim)
+    with pytest.raises(ValueError, match="one shard per device"):
+        shard_index(idx, 2, mesh=mesh)
 
 
 def test_timeline_retriever_matches_single_device(small_corpus, small_index):

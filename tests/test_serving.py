@@ -279,8 +279,11 @@ def test_submit_flush_tickets(serve_corpus, base_timeline):
                                   jnp.asarray(c.queries[0:1, :16]), CFG)
     np.testing.assert_array_equal(t_short.result()[1],
                                   np.asarray(ref_short.doc_ids)[0])
-    np.testing.assert_array_equal(t_short.result()[0],
-                                  np.asarray(ref_short.scores)[0])
+    # Ids are exact; scores agree to rounding only: XLA may reassociate the
+    # padded query's per-term sum (32 slots, 16 masked) differently from
+    # the 16-term prefix's, which moves the last float32 bit.
+    np.testing.assert_allclose(t_short.result()[0],
+                               np.asarray(ref_short.scores)[0], rtol=1e-6)
     ref_full = retrieve_timeline(base_timeline,
                                  jnp.asarray(c.queries[1:2]), CFG)
     np.testing.assert_array_equal(t_full.result()[1],
