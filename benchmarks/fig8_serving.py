@@ -11,36 +11,27 @@ host memory and computing only the newest. Rows:
     fig8,serving,uncached,docs=<n>,gens=<G>,<us_per_query>
     fig8,serving,cold,<us_per_query>,hit_rate=<r>
     fig8,serving,warm,<us_per_query>,hit_rate=<r>,speedup=x<s>,p50_ms=...
-    fig8,serving,traced,<us_per_query>,overhead=x<o>,spans=<n>
     fig8,serving,footprint,0.0,cache_kb=<c>,timeline_mb=<t>,bpe=<b>
 
 ``speedup`` is uncached/warm per-query time on the SAME stream — the
 acceptance signal (>1x: the cache pays for itself on repeated traffic).
-``traced`` reruns the warm stream under a live span tracer
-(docs/OBSERVABILITY.md); ``overhead`` = traced/warm per-query time, the
-acceptance number for "tracing enabled stays cheap", and the captured
-spans + summary land in ``BENCH_trace.json`` (CI artifact, same upload
-glob as the other BENCH files). The footprint row carries the byte
+The footprint row carries the byte
 accounting (cache occupancy + timeline footprint incl. manifest overhead)
 that capacity planning needs.
 """
 from __future__ import annotations
 
-import json
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import obs
 from repro.core import (EngineConfig, ShardedTimeline, build_index,
                         new_generation, retrieve_timeline, timeline_footprint)
 from repro.serving import RetrievalService
 
 from .common import TH, TH_R, bench_corpus, row
-
-TRACE_PATH = "BENCH_trace.json"
 
 N_GENS = 4
 PER_GEN = 512
@@ -114,36 +105,6 @@ def run() -> list[str]:
         f"speedup=x{t_base / t_warm:.2f},"
         f"p50_ms={stats['warm_latency']['p50_ms']:.2f},"
         f"p99_ms={stats['warm_latency']['p99_ms']:.2f}"))
-
-    # traced pass: the SAME warm stream under a live tracer — results are
-    # bit-exact with tracing on (spans never touch values), so the only
-    # signal is the time delta
-    with obs.tracing(capacity=32768) as tracer:
-        t_traced = _time_stream(lambda b: svc.query(b), batches)
-    spans = tracer.finished()
-    overhead = t_traced / t_warm
-    rows.append(row("fig8,serving,traced", t_traced * 1e6,
-                    f"overhead=x{overhead:.2f},spans={len(spans)}"))
-
-    by_name: dict = {}
-    for sp in spans:
-        agg = by_name.setdefault(sp["name"], {"count": 0, "total_s": 0.0})
-        agg["count"] += 1
-        agg["total_s"] += sp["duration_s"]
-    with open(TRACE_PATH, "w") as f:
-        json.dump({
-            "summary": {
-                "warm_us_per_query": t_warm * 1e6,
-                "traced_us_per_query": t_traced * 1e6,
-                "overhead": overhead,
-                "spans": len(spans),
-                "dropped": tracer.dropped,
-                "by_name": {k: {"count": v["count"],
-                                "total_ms": v["total_s"] * 1e3}
-                            for k, v in sorted(by_name.items())},
-            },
-            "spans": spans,
-        }, f, indent=1, default=str)
 
     fp = timeline_footprint(timeline)
     rows.append(row(

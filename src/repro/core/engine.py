@@ -263,6 +263,11 @@ def _doc_pass(index: PackedIndex, cfg: EngineConfig) -> Optional[jax.Array]:
 # public phase-split entry points.
 # ---------------------------------------------------------------------------
 
+# Each phase traces under ``jax.named_scope("engine.phaseN")``: its
+# operations carry the name in their metadata, so a device trace can charge
+# them to the phase. The scopes change no computation.
+
+@jax.named_scope("engine.phase1")
 def _phase1(q: jax.Array, index: PackedIndex, cfg: EngineConfig,
             q_mask: Optional[jax.Array] = None):
     """-> (cs (n_q, n_c), bits (n_c,) u32, bitmap (n_docs,) bool).
@@ -292,6 +297,7 @@ def _compact_candidates(bitmap: jax.Array, cfg: EngineConfig):
     return cand_ids, cand_valid
 
 
+@jax.named_scope("engine.phase2")
 def _phase2(index: PackedIndex, token_mask: jax.Array, bits: jax.Array,
             bitmap: jax.Array, cfg: EngineConfig) -> jax.Array:
     """Unfused bit-vector pre-filter -> sel1 (n_filter,) int32."""
@@ -326,35 +332,38 @@ def _phase12(q: jax.Array, index: PackedIndex, token_mask: jax.Array,
         cs, bits, bitmap = _phase1(q, index, cfg, q_mask)
         return cs, _phase2(index, token_mask, bits, bitmap, cfg)
     # Fused path: the bit table never leaves the kernel; no full-corpus f.
-    cs = centroid_scores(q, index.centroids, cfg.cs_dtype)
-    probe_ids = bitvector.masked_topk_centroids(cs, cfg.th, cfg.nprobe,
-                                                q_mask)
-    bitmap = candidate_bitmap(index.ivf, index.ivf_lens, probe_ids,
-                              index.codes.shape[0])
-    if cfg.candidate_mode == "compact":
-        # Filter BEFORE compaction: non-passing docs never enter the
-        # fixed-size candidate buffer, matching the unfused path's
-        # pre-filtered bitmap bit for bit.
-        doc_pass = _doc_pass(index, cfg)
-        if doc_pass is not None:
-            bitmap = bitmap & doc_pass
-        cand_ids, cand_valid = _compact_candidates(bitmap, cfg)
-        c_codes = jnp.take(index.codes, cand_ids, axis=0)
-        c_mask = jnp.take(token_mask, cand_ids, axis=0)
-        _, sel1_local, _ = kops.prefilter(cs, cfg.th, c_codes, c_mask,
-                                          cand_valid, cfg.n_filter, q_mask)
-        sel1 = jnp.take(cand_ids, sel1_local)
-    else:
-        # score_all: the predicate words ride into the megakernel and the
-        # static word-combine plan ANDs them into the candidate bitmap
-        # INSIDE the launch — no host-side full-corpus pass mask.
-        plan = None if cfg.doc_filter is None else cfg.doc_filter.clauses
-        _, sel1, _ = kops.prefilter(cs, cfg.th, index.codes, token_mask,
-                                    bitmap, cfg.n_filter, q_mask,
-                                    pred_words=index.pred_words, plan=plan)
+    with jax.named_scope("engine.phase1"):
+        cs = centroid_scores(q, index.centroids, cfg.cs_dtype)
+        probe_ids = bitvector.masked_topk_centroids(cs, cfg.th, cfg.nprobe,
+                                                    q_mask)
+        bitmap = candidate_bitmap(index.ivf, index.ivf_lens, probe_ids,
+                                  index.codes.shape[0])
+    with jax.named_scope("engine.phase2"):
+        if cfg.candidate_mode == "compact":
+            # Filter BEFORE compaction: non-passing docs never enter the
+            # fixed-size candidate buffer, matching the unfused path's
+            # pre-filtered bitmap bit for bit.
+            doc_pass = _doc_pass(index, cfg)
+            if doc_pass is not None:
+                bitmap = bitmap & doc_pass
+            cand_ids, cand_valid = _compact_candidates(bitmap, cfg)
+            c_codes = jnp.take(index.codes, cand_ids, axis=0)
+            c_mask = jnp.take(token_mask, cand_ids, axis=0)
+            _, sel1_local, _ = kops.prefilter(cs, cfg.th, c_codes, c_mask,
+                                              cand_valid, cfg.n_filter, q_mask)
+            sel1 = jnp.take(cand_ids, sel1_local)
+        else:
+            # score_all: the predicate words ride into the megakernel and the
+            # static word-combine plan ANDs them into the candidate bitmap
+            # INSIDE the launch — no host-side full-corpus pass mask.
+            plan = None if cfg.doc_filter is None else cfg.doc_filter.clauses
+            _, sel1, _ = kops.prefilter(cs, cfg.th, index.codes, token_mask,
+                                        bitmap, cfg.n_filter, q_mask,
+                                        pred_words=index.pred_words, plan=plan)
     return cs, sel1.astype(jnp.int32)
 
 
+@jax.named_scope("engine.phase3")
 def _phase3(index: PackedIndex, token_mask: jax.Array, cs: jax.Array,
             sel1: jax.Array, cfg: EngineConfig,
             q_mask: Optional[jax.Array] = None) -> jax.Array:
@@ -378,6 +387,7 @@ def _phase3(index: PackedIndex, token_mask: jax.Array, cs: jax.Array,
     return jnp.take(sel1, sel2_local)                            # (nd,)
 
 
+@jax.named_scope("engine.phase4")
 def _phase4(index: PackedIndex, token_mask: jax.Array, q: jax.Array,
             cs: jax.Array, sel2: jax.Array, cfg: EngineConfig,
             q_mask: Optional[jax.Array] = None):
@@ -430,18 +440,21 @@ def _phase34(index: PackedIndex, token_mask: jax.Array, q: jax.Array,
         return _phase4(index, token_mask, q, cs, sel2, cfg, q_mask)
     # Fused path: S̄, the phase-3 selection, the Eq. 5/6 PQ scores and the
     # final top-k never leave the kernel; codes/residuals are gathered ONCE
-    # for the phase-2 survivors instead of once per phase.
-    q_rot = q @ index.opq_rotation
-    lut = build_lut(q_rot, index.pq)                             # (n_q, m, K)
-    s1_codes = jnp.take(index.codes, sel1, axis=0)               # (nf, cap)
-    s1_res = jnp.take(index.res_codes, sel1, axis=0)
-    s1_mask = jnp.take(token_mask, sel1, axis=0)
-    doc_pass = _doc_pass(index, cfg)
-    s1_pass = None if doc_pass is None else jnp.take(doc_pass, sel1)
-    top_scores, top_pos, _, _ = kops.pqinter(
-        cs.T, lut, s1_codes, s1_res, s1_mask, cfg.th_r, cfg.n_docs, cfg.k,
-        q_mask, doc_pass=s1_pass)
-    return top_scores, jnp.take(sel1, top_pos)
+    # for the phase-2 survivors instead of once per phase. The gathers are
+    # phase 3's; the launch, phase-3 cut included, is named phase 4.
+    with jax.named_scope("engine.phase3"):
+        s1_codes = jnp.take(index.codes, sel1, axis=0)           # (nf, cap)
+        s1_res = jnp.take(index.res_codes, sel1, axis=0)
+        s1_mask = jnp.take(token_mask, sel1, axis=0)
+        doc_pass = _doc_pass(index, cfg)
+        s1_pass = None if doc_pass is None else jnp.take(doc_pass, sel1)
+    with jax.named_scope("engine.phase4"):
+        q_rot = q @ index.opq_rotation
+        lut = build_lut(q_rot, index.pq)                         # (n_q, m, K)
+        top_scores, top_pos, _, _ = kops.pqinter(
+            cs.T, lut, s1_codes, s1_res, s1_mask, cfg.th_r, cfg.n_docs,
+            cfg.k, q_mask, doc_pass=s1_pass)
+        return top_scores, jnp.take(sel1, top_pos)
 
 
 # ---------------------------------------------------------------------------
@@ -484,32 +497,34 @@ def _phase12_batch(index: PackedIndex, token_mask: jax.Array,
         return _vmap1(
             lambda q, m: _phase12(q, index, token_mask, cfg, m),
             queries, q_masks)
-    cs = jax.vmap(
-        lambda q: centroid_scores(q, index.centroids, cfg.cs_dtype))(queries)
-    probe_ids = _vmap1(
-        lambda c, m: bitvector.masked_topk_centroids(c, cfg.th, cfg.nprobe,
-                                                     m), cs, q_masks)
-    bitmap = jax.vmap(
-        lambda p: candidate_bitmap(index.ivf, index.ivf_lens, p,
-                                   index.codes.shape[0]))(probe_ids)
-    if cfg.candidate_mode == "compact":
-        # Same pre-compaction filter as the single-query fused path, shared
-        # across the batch (the pass mask is query-independent).
-        doc_pass = _doc_pass(index, cfg)
-        if doc_pass is not None:
-            bitmap = bitmap & doc_pass[None, :]
-        cand_ids, cand_valid = jax.vmap(
-            lambda b: _compact_candidates(b, cfg))(bitmap)
-        c_codes = jnp.take(index.codes, cand_ids, axis=0)  # (B, cand_cap, cap)
-        c_mask = jnp.take(token_mask, cand_ids, axis=0)
-        _, sel1_local, _ = kops.prefilter_batched(
-            cs, cfg.th, c_codes, c_mask, cand_valid, cfg.n_filter, q_masks)
-        sel1 = jnp.take_along_axis(cand_ids, sel1_local, axis=1)
-    else:
-        plan = None if cfg.doc_filter is None else cfg.doc_filter.clauses
-        _, sel1, _ = kops.prefilter_batched(
-            cs, cfg.th, index.codes, token_mask, bitmap, cfg.n_filter,
-            q_masks, pred_words=index.pred_words, plan=plan)
+    with jax.named_scope("engine.phase1"):
+        cs = jax.vmap(
+            lambda q: centroid_scores(q, index.centroids, cfg.cs_dtype))(queries)
+        probe_ids = _vmap1(
+            lambda c, m: bitvector.masked_topk_centroids(c, cfg.th, cfg.nprobe,
+                                                         m), cs, q_masks)
+        bitmap = jax.vmap(
+            lambda p: candidate_bitmap(index.ivf, index.ivf_lens, p,
+                                       index.codes.shape[0]))(probe_ids)
+    with jax.named_scope("engine.phase2"):
+        if cfg.candidate_mode == "compact":
+            # Same pre-compaction filter as the single-query fused path, shared
+            # across the batch (the pass mask is query-independent).
+            doc_pass = _doc_pass(index, cfg)
+            if doc_pass is not None:
+                bitmap = bitmap & doc_pass[None, :]
+            cand_ids, cand_valid = jax.vmap(
+                lambda b: _compact_candidates(b, cfg))(bitmap)
+            c_codes = jnp.take(index.codes, cand_ids, axis=0)  # (B, cand_cap, cap)
+            c_mask = jnp.take(token_mask, cand_ids, axis=0)
+            _, sel1_local, _ = kops.prefilter_batched(
+                cs, cfg.th, c_codes, c_mask, cand_valid, cfg.n_filter, q_masks)
+            sel1 = jnp.take_along_axis(cand_ids, sel1_local, axis=1)
+        else:
+            plan = None if cfg.doc_filter is None else cfg.doc_filter.clauses
+            _, sel1, _ = kops.prefilter_batched(
+                cs, cfg.th, index.codes, token_mask, bitmap, cfg.n_filter,
+                q_masks, pred_words=index.pred_words, plan=plan)
     return cs, sel1.astype(jnp.int32)
 
 
@@ -531,18 +546,22 @@ def _phase34_batch(index: PackedIndex, token_mask: jax.Array,
                 lambda q, c, s, m: _phase34(index, token_mask, q, c, s, cfg,
                                             m))(queries, cs, sel1, q_masks)
         return RetrievalResult(scores, ids)
-    q_rot = jax.vmap(lambda q: q @ index.opq_rotation)(queries)
-    lut = jax.vmap(lambda qr: build_lut(qr, index.pq))(q_rot)
-    s1_codes = jnp.take(index.codes, sel1, axis=0)           # (B, nf, cap)
-    s1_res = jnp.take(index.res_codes, sel1, axis=0)
-    s1_mask = jnp.take(token_mask, sel1, axis=0)
-    doc_pass = _doc_pass(index, cfg)
-    s1_pass = None if doc_pass is None else jnp.take(doc_pass, sel1)  # (B,nf)
-    top_scores, top_pos, _, _ = kops.pqinter_batched(
-        jnp.swapaxes(cs, -1, -2), lut, s1_codes, s1_res, s1_mask, cfg.th_r,
-        cfg.n_docs, cfg.k, q_masks, doc_pass=s1_pass)
-    return RetrievalResult(top_scores,
-                           jnp.take_along_axis(sel1, top_pos, axis=1))
+    # named as in the single-query fused path (_phase34)
+    with jax.named_scope("engine.phase3"):
+        s1_codes = jnp.take(index.codes, sel1, axis=0)       # (B, nf, cap)
+        s1_res = jnp.take(index.res_codes, sel1, axis=0)
+        s1_mask = jnp.take(token_mask, sel1, axis=0)
+        doc_pass = _doc_pass(index, cfg)
+        s1_pass = None if doc_pass is None else \
+            jnp.take(doc_pass, sel1)                         # (B, nf)
+    with jax.named_scope("engine.phase4"):
+        q_rot = jax.vmap(lambda q: q @ index.opq_rotation)(queries)
+        lut = jax.vmap(lambda qr: build_lut(qr, index.pq))(q_rot)
+        top_scores, top_pos, _, _ = kops.pqinter_batched(
+            jnp.swapaxes(cs, -1, -2), lut, s1_codes, s1_res, s1_mask,
+            cfg.th_r, cfg.n_docs, cfg.k, q_masks, doc_pass=s1_pass)
+        return RetrievalResult(top_scores,
+                               jnp.take_along_axis(sel1, top_pos, axis=1))
 
 
 def _retrieve_batch(index: PackedIndex, queries: jax.Array,

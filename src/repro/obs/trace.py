@@ -31,6 +31,12 @@ the serving loop it instruments (docs/SERVING.md); ``jax`` dispatch is
 asynchronous, so a span around an un-``block_until_ready``'d call times
 the dispatch, not the device work — span names note ``dispatch`` where
 that applies.
+
+While a :class:`Tracer` is enabled, every span also opens a
+``jax.profiler.TraceAnnotation`` of the span's name (no attributes) around
+its two clock reads, so a profiler capture holds the program's spans on
+the host thread that ran them, on the profiler's clock, beside the device
+timeline. ``record`` events are measured in the past and are not mirrored.
 """
 from __future__ import annotations
 
@@ -38,6 +44,8 @@ import json
 import time
 from collections import deque
 from typing import Callable, Optional
+
+from jax.profiler import TraceAnnotation
 
 
 class _NoopSpan:
@@ -87,15 +95,16 @@ class Span:
     """One open span — a context manager handed out by :meth:`Tracer.span`.
 
     ``__enter__`` assigns ids (parented under the innermost open span),
-    reads the clock, and pushes onto the tracer's stack; ``__exit__`` pops
-    and emits the finished record into the ring. ``set(**attrs)`` adds
-    attributes mid-span (e.g. a hit count known only after the lookup
-    loop). Attribute values should be JSON-able; the exporter falls back
-    to ``str()`` for anything that is not.
+    pushes onto the tracer's stack, opens the span's profiler annotation
+    and reads the clock; ``__exit__`` reads the clock, closes the
+    annotation, pops and emits the finished record into the ring.
+    ``set(**attrs)`` adds attributes mid-span (e.g. a hit count known only
+    after the lookup loop). Attribute values should be JSON-able; the
+    exporter falls back to ``str()`` for anything that is not.
     """
 
     __slots__ = ("_tracer", "name", "attrs", "start", "span_id",
-                 "parent_id", "trace_id")
+                 "parent_id", "trace_id", "_annotation")
 
     def __init__(self, tracer: "Tracer", name: str, attrs: dict):
         """Built by :meth:`Tracer.span`; not started until ``__enter__``."""
@@ -106,6 +115,7 @@ class Span:
         self.span_id = 0
         self.parent_id: Optional[int] = None
         self.trace_id = 0
+        self._annotation = None
 
     def set(self, **attrs) -> "Span":
         """Merge attributes into the span; returns itself for chaining."""
@@ -114,8 +124,9 @@ class Span:
 
     def __enter__(self) -> "Span":
         """Start the span: assign ids, parent under the innermost open
-        span (a root span starts a new trace), read the clock LAST so the
-        bookkeeping is outside the timed region."""
+        span (a root span starts a new trace), open the profiler
+        annotation, read the clock LAST so the bookkeeping is outside the
+        timed region."""
         t = self._tracer
         self.span_id = t._next_id()
         if t._stack:
@@ -126,16 +137,20 @@ class Span:
             self.parent_id = None
             self.trace_id = self.span_id
         t._stack.append(self)
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
         self.start = t.clock()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        """Finish the span: read the clock FIRST, pop the stack (popping
-        through any unexited children so one leaked span cannot corrupt
-        the hierarchy forever), emit the record. An exception inside the
-        span marks ``error: true`` and propagates (never swallowed)."""
+        """Finish the span: read the clock FIRST, close the profiler
+        annotation, pop the stack (popping through any unexited children
+        so one leaked span cannot corrupt the hierarchy forever), emit the
+        record. An exception inside the span marks ``error: true`` and
+        propagates (never swallowed)."""
         t = self._tracer
         end = t.clock()
+        self._annotation.__exit__(exc_type, exc, tb)
         while t._stack and t._stack.pop() is not self:
             pass
         rec = {
@@ -200,7 +215,8 @@ class Tracer:
         For durations measured with a foreign clock (the batcher's
         injectable deadline clock, a staged-swap wait): the span's
         ``start`` is back-dated to ``clock() - duration_s``, and it
-        parents under the innermost open span like any other.
+        parents under the innermost open span like any other. The event
+        lies in the past, so no profiler annotation mirrors it.
         """
         end = self.clock()
         sid = self._next_id()

@@ -195,9 +195,10 @@ class MicroBatcher:
 
         Telemetry per drain: the drained queries' queue wait (oldest
         entry's, the batch's worst case) is recorded as a
-        ``batcher.queue_wait`` span on the current tracer, and every
-        drained query that waited STRICTLY longer than ``max_delay_s``
-        bumps ``deadline_misses``.
+        ``batcher.queue_wait`` span on the current tracer, with every
+        drained query's submit-to-drain wait in its ``waits_s``
+        attribute, and every drained query that waited STRICTLY longer
+        than ``max_delay_s`` bumps ``deadline_misses``.
         """
         if not self._queries:
             return None
@@ -207,10 +208,10 @@ class MicroBatcher:
                and self._filters[n] == doc_filter):
             n += 1
         now = self.clock()
-        self.deadline_misses += sum(
-            1 for t in self._submits[:n] if now - t > self.max_delay_s)
-        trace.record("batcher.queue_wait", now - self._submits[0],
-                     batch=n, pending=len(self._queries) - n)
+        waits = [now - t for t in self._submits[:n]]
+        self.deadline_misses += sum(1 for w in waits if w > self.max_delay_s)
+        trace.record("batcher.queue_wait", waits[0], batch=n,
+                     pending=len(self._queries) - n, waits_s=waits)
         qb = QueryBatch(np.stack(self._queries[:n]),
                         np.stack(self._masks[:n]))
         tickets = self._tickets[:n]

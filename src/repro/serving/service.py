@@ -394,8 +394,9 @@ class RetrievalService:
             qb, tickets, doc_filter = drained
             with trace.span("service.flush", batch=len(tickets)):
                 res = self._execute(qb.q, qb.q_mask, doc_filter=doc_filter)
-                scores = np.asarray(res.scores)
-                ids = np.asarray(res.doc_ids)
+                with trace.span("service.fetch"):
+                    scores = np.asarray(res.scores)
+                    ids = np.asarray(res.doc_ids)
                 for j, t in enumerate(tickets):
                     t._fill(scores[j], ids[j])
 
@@ -441,7 +442,8 @@ class RetrievalService:
                 "micro-batcher never drains an empty batch; direct "
                 "callers must pass >= 1 query)")
         cfg_fp = self._cfg_fp_for(doc_filter)
-        qfps = [query_fingerprint(q[i], masks[i]) for i in range(n)]
+        with trace.span("service.fingerprint", batch=n):
+            qfps = [query_fingerprint(q[i], masks[i]) for i in range(n)]
         warm = np.full(n, self._n_cacheable > 0)
         n_epochs = len(self._plans)
         epoch_parts = []
@@ -493,14 +495,20 @@ class RetrievalService:
                                 else:
                                     res = plan(jnp.asarray(mq),
                                                jnp.asarray(mm), doc_filter)
-                                ms = np.asarray(res.scores)[:len(miss)]
-                                # epoch-local -> global ids BEFORE caching,
-                                # so cached and fresh partials merge
-                                # identically (epoch offsets are stable:
-                                # compaction and re-epoching both preserve
-                                # every surviving doc's global id)
-                                mi = np.asarray(res.doc_ids)[:len(miss)] \
-                                    + np.int32(eoff)
+                                # the wait apart from the copy: np.asarray
+                                # would block here anyway
+                                with trace.span("service.device_wait"):
+                                    jax.block_until_ready(res)
+                                with trace.span("service.fetch"):
+                                    ms = np.asarray(res.scores)[:len(miss)]
+                                    # epoch-local -> global ids BEFORE
+                                    # caching, so cached and fresh partials
+                                    # merge identically (epoch offsets are
+                                    # stable: compaction and re-epoching
+                                    # both preserve every surviving doc's
+                                    # global id)
+                                    mi = np.asarray(res.doc_ids)[:len(miss)] \
+                                        + np.int32(eoff)
                             for j, i in enumerate(miss):
                                 rows[i] = (ms[j], mi[j])
                                 if cacheable:
@@ -516,7 +524,8 @@ class RetrievalService:
             with trace.span("service.merge", epochs=n_epochs, final=True):
                 merged = epoch_parts[0] if n_epochs == 1 else \
                     merge_partial_topk_by_rank(epoch_parts, self.cfg.k)
-                jax.block_until_ready(merged)
+                with trace.span("service.device_wait"):
+                    jax.block_until_ready(merged)
         self.metrics.record_batch(n, int(warm.sum()), self.clock() - t0,
                                   n_filtered=0 if doc_filter is None else n)
         return merged
