@@ -83,12 +83,18 @@ def filter_score_batch(bits: jax.Array, codes: jax.Array,
     return jax.vmap(filter_score, in_axes=(0, None, None))(bits, codes, token_mask)
 
 
+def _first_of(a, b):
+    """The (key, pos) pair ahead in the order greater key, then lower pos."""
+    take_a = (a[0] > b[0]) | ((a[0] == b[0]) & (a[1] < b[1]))
+    return jnp.where(take_a, a[0], b[0]), jnp.where(take_a, a[1], b[1])
+
+
 def masked_topk_centroids(cs: jax.Array, th: float, nprobe: int,
                           q_mask: Optional[jax.Array] = None) -> jax.Array:
     """Top-nprobe centroid ids per query term, restricted to the survivors of
     the threshold (paper §4.1: the pre-filter 'tears down' the number of
-    evaluated elements; the TPU-native equivalent masks non-survivors to -inf
-    so top_k never ranks them above any survivor).
+    evaluated elements; the TPU-native equivalent ranks non-survivors at
+    ``cs - 1e6`` so the selection never ranks them above any survivor).
 
     The ranking runs in f32 regardless of the CS dtype: the old code
     computed ``cs - 1e6`` in the CS dtype, and under reduced-precision CS
@@ -101,17 +107,47 @@ def masked_topk_centroids(cs: jax.Array, th: float, nprobe: int,
     higher-scoring ones). For f32 CS this is bit-identical to the old
     behavior.
 
+    The selection is nprobe statically unrolled passes over the row, each a
+    single reduction that picks the greatest remaining score and, among
+    equal scores, the lowest index; a pass skips every entry at or ahead of
+    the previous pick in that order, so nothing is written back. It is not
+    ``jax.lax.top_k`` because the TPU lowers that to a full sort of the
+    2^18-wide row to pick 4 entries. Scores compare as ``lax.top_k``'s
+    comparator does (the float32 total order: -0.0 below +0.0, NaNs at the
+    ends), so the ids and their order, ties included, equal ``lax.top_k``'s.
+
     q_mask : optional (..., n_q) bool — masked terms probe NOTHING: their
              rows are returned as the one-past-end sentinel ``n_c``, which
              ``candidate_bitmap`` treats as an empty list.
     cs -> (..., n_q, nprobe) int32.
     """
+    n_c = cs.shape[-1]
+    if nprobe > n_c:
+        raise ValueError(f"nprobe={nprobe} exceeds the {n_c} centroids")
     cs32 = cs.astype(jnp.float32)
     masked = jnp.where(cs > th, cs32, cs32 - 1e6)
-    _, idx = jax.lax.top_k(masked, nprobe)
-    idx = idx.astype(jnp.int32)
+    # float32 -> int32 key, monotone in the float32 total order
+    bits = jax.lax.bitcast_convert_type(masked, jnp.int32)
+    key = jnp.where(bits < 0, bits ^ jnp.int32(0x7FFFFFFF), bits)
+    pos = jax.lax.broadcasted_iota(jnp.int32, key.shape, key.ndim - 1)
+    lowest = jnp.int32(np.iinfo(np.int32).min)
+    picks = []
+    k_last = p_last = None
+    for _ in range(nprobe):
+        k_, p_ = key, pos
+        if picks:
+            # Picked entries become (lowest, n_c), which loses to every entry
+            # still in the row, even one whose key is ``lowest`` itself.
+            kl, pl = k_last[..., None], p_last[..., None]
+            left = (key < kl) | ((key == kl) & (pos > pl))
+            k_ = jnp.where(left, key, lowest)
+            p_ = jnp.where(left, pos, n_c)
+        k_last, p_last = jax.lax.reduce((k_, p_), (lowest, jnp.int32(n_c)),
+                                        _first_of, (key.ndim - 1,))
+        picks.append(p_last)
+    idx = jnp.stack(picks, axis=-1)
     if q_mask is not None:
-        idx = jnp.where(q_mask[..., :, None], idx, jnp.int32(cs.shape[-1]))
+        idx = jnp.where(q_mask[..., :, None], idx, jnp.int32(n_c))
     return idx
 
 

@@ -186,6 +186,64 @@ def test_masked_terms_probe_nothing():
     np.testing.assert_array_equal(idx[2], ref[2])
 
 
+def _topk_reference(cs, th, nprobe, q_mask=None):
+    """The selection as ``jax.lax.top_k`` makes it, on the same masked scores."""
+    cs32 = cs.astype(jnp.float32)
+    _, idx = jax.lax.top_k(jnp.where(cs > th, cs32, cs32 - 1e6), nprobe)
+    if q_mask is not None:
+        idx = jnp.where(q_mask[..., :, None], idx, cs.shape[-1])
+    return np.asarray(idx)
+
+
+def _selection_case(name):
+    """-> (cs, th, q_mask) for one exactness case."""
+    rng = np.random.default_rng(7)
+    q_mask = None
+    th = 0.4
+    if name == "random":
+        cs = rng.normal(size=(2, 6, 700)).astype(np.float32)
+    elif name == "constant_no_survivor":    # every slot ties at 0.1 - 1e6
+        cs = np.full((3, 500), 0.1, np.float32)
+    elif name == "tied_survivors":
+        cs = rng.choice(np.float32([0.5, 0.75, 0.1]), size=(4, 600))
+    elif name == "fewer_survivors_than_nprobe":
+        cs = rng.uniform(-1.0, 0.3, size=(4, 600)).astype(np.float32)
+        cs[:, [17, 300]] = [0.9, 0.6]
+        cs[:, 400:420] = 0.25               # tied non-survivors
+    elif name == "q_mask":
+        cs = rng.normal(size=(2, 5, 300)).astype(np.float32)
+        q_mask = jnp.asarray([[True, False, True, True, False],
+                              [False, False, True, False, True]])
+    elif name == "bf16":
+        cs = jnp.asarray(rng.normal(size=(4, 800)), jnp.bfloat16)
+    elif name == "float_specials":          # the float32 total order
+        th = -2.0
+        cs = np.tile(np.float32([0.0, -0.0, np.inf, np.nan, -np.nan, -np.inf,
+                                 1.0, -0.0, 0.0, -3.0]), (2, 1))
+    else:                                   # one row at MS MARCO's n_c
+        cs = rng.normal(size=(1, 1 << 18)).astype(np.float32)
+        cs[0, [5, 70_000, 262_143]] = cs.max()    # a tie at the top
+    return jnp.asarray(cs), th, q_mask
+
+
+@pytest.mark.parametrize("nprobe", [1, 4, 8])
+@pytest.mark.parametrize("case", [
+    "random", "constant_no_survivor", "tied_survivors",
+    "fewer_survivors_than_nprobe", "q_mask", "bf16", "float_specials",
+    "n_c_2_18"])
+def test_masked_topk_equals_lax_top_k(case, nprobe):
+    """The sort-free selection returns ``lax.top_k``'s ids in its order, ties
+    to the lower index, eagerly and under jit."""
+    cs, th, q_mask = _selection_case(case)
+    ref = _topk_reference(cs, th, nprobe, q_mask)
+    eager = np.asarray(masked_topk_centroids(cs, th, nprobe, q_mask))
+    jitted = np.asarray(jax.jit(masked_topk_centroids, static_argnums=(1, 2))(
+        cs, th, nprobe, q_mask))
+    assert eager.dtype == np.int32
+    np.testing.assert_array_equal(eager, ref)
+    np.testing.assert_array_equal(jitted, ref)
+
+
 def test_sentinel_probes_add_no_candidates():
     """candidate_bitmap must treat sentinel probe ids as empty lists."""
     ivf = jnp.asarray(np.arange(12, dtype=np.int32).reshape(4, 3))

@@ -8,6 +8,7 @@ import: only one process may load the TPU library, and pytest-xdist workers
 all import every test file.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,6 +99,41 @@ def test_shardmap_plan_compiles_on_v5e_2x2(topo):
     assert "all-gather" in compiled.as_text()
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def _sorts_over_last_axis(lowered, n: int):
+    """The ``chlo.top_k`` and ``stablehlo.sort`` ops of a lowered module whose
+    first operand's last axis has length ``n``."""
+    found = []
+
+    def walk(op):
+        for region in op.regions:
+            for block in region.blocks:
+                for inner in block.operations:
+                    inner = inner.operation
+                    if inner.name in ("chlo.top_k", "stablehlo.sort"):
+                        dims = re.match(r"tensor<([0-9x]+)x", str(
+                            inner.operands[0].type))
+                        if dims and int(dims.group(1).split("x")[-1]) == n:
+                            found.append(inner.name)
+                    walk(inner)
+
+    walk(lowered.compiler_ir("stablehlo").operation)
+    return found
+
+
+def test_retrieve_sorts_no_centroid_row():
+    """Phase 1 picks its nprobe centroids without sorting the n_c scores of a
+    query term: the TPU lowers a top_k over them to a full sort of the row.
+    Lowered at MS MARCO widths, batch 8, on any backend."""
+    row = jax.ShapeDtypeStruct((8, N_Q, N_C), jnp.float32)
+    assert _sorts_over_last_axis(
+        jax.jit(lambda x: jax.lax.top_k(x, 4)).lower(row), N_C) == [
+            "chlo.top_k"]               # the check sees the op it guards
+    q = jax.ShapeDtypeStruct((8, N_Q, D), jnp.float32)
+    qm = jax.ShapeDtypeStruct((8, N_Q), jnp.bool_)
+    lowered = _retrieve_jit.lower(_index_shapes(N_DOCS, None), q, CFG, qm)
+    assert _sorts_over_last_axis(lowered, N_C) == []
 
 
 # Toy widths: Mosaic refuses both megakernels at any width. A change that
