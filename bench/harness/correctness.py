@@ -21,6 +21,16 @@ are compared, each with its limit from the configuration's ``limits``:
   score by which it is wrong; an answer that no pool the reference keeps
   can judge reads infinite.
 
+A deployment block-sharded over several chips answers with two levels:
+each shard makes EMVB's three cuts over its own passages and gives its
+top k, and the top k of those is served. So there the reference runs per
+shard (:func:`harness.reference.reference_shards`), ``score_gap`` looks a
+served passage up in its own shard, by local id, and ``selection_gap`` is
+the least ``eps`` at which, in every shard at once, some order of the ties
+selects the served passages that shard holds as the top of its answer,
+and no passage of its top k that was not served beats the lowest served
+Eq. 6 by more than ``eps``. On one shard that is the judgement above.
+
 A run is correct when every query due in the window was answered and both
 numbers are within their limits.
 """
@@ -88,8 +98,8 @@ class _Query:
         self.e[e_rows] = e
         self.e = self.e[rows]
         self.a1, self.t1, self.in_l = a1[rows], t1[rows], in_l[rows]
-        self.min_e_l = float(self.e[self.in_l].min())
-        self.min_ci_l = float(self.ci[self.in_l].min())
+        self.min_e_l = float(self.e[self.in_l].min(initial=math.inf))
+        self.min_ci_l = float(self.ci[self.in_l].min(initial=math.inf))
 
     def _above(self, mask: np.ndarray, t: np.ndarray) -> np.ndarray:
         """Per threshold in ``t``, how many of ``mask`` have Eq. 2 > t."""
@@ -100,19 +110,21 @@ class _Query:
         cum = np.concatenate([[0], np.cumsum(mask)])
         return cum[-1] - cum[np.searchsorted(self.ci, t, side="left")]
 
-    def feasible(self, eps: float) -> bool:
+    def feasible(self, eps: float, bound: float | None = None) -> bool:
         """Does some order of the ties select exactly the served k, with
         Eq. 2 and Eq. 6 compared to within ``eps``?
 
         Phase 3 keeps the passages of the phase-2 survivors P3 whose Eq. 2
         is at least some threshold c and drops those below c - eps; the
         served k must be kept, and no kept passage beyond them may have an
-        Eq. 6 over the served k's lowest by more than eps ("bad")."""
+        Eq. 6 over ``bound`` (default: the served k's lowest) by more than
+        eps ("bad")."""
+        bound = self.min_e_l if bound is None else bound
         c = np.unique(self.ci)
         c = c[(c <= self.min_ci_l) & (c > self.c_floor)]
         if not len(c):
             return False
-        good = ~(~self.in_l & (self.e > self.min_e_l + eps))
+        good = ~(~self.in_l & (self.e > bound + eps))
         hi_t = c + eps
 
         def hi(mask):
@@ -135,43 +147,76 @@ class _Query:
         ok &= self.nd <= a_hi + y_hi + np.minimum(h_hi + b_hi, self.cnt1)
         return bool(ok.any())
 
-    def gap(self) -> float:
+    def gap(self, bound: float | None = None) -> float:
         """The least ``eps`` at which the answer is feasible (to 1%)."""
         if self.reason is not None:
             return math.inf
-        if self.feasible(0.0):
+        if self.feasible(0.0, bound):
             return 0.0
-        if not self.feasible(EPS_MAX):
+        if not self.feasible(EPS_MAX, bound):
             return math.inf
         lo, hi = 1e-9, EPS_MAX
-        if self.feasible(lo):
+        if self.feasible(lo, bound):
             return lo
         while hi / lo > 1.01:
             mid = math.sqrt(lo * hi)
-            lo, hi = (lo, mid) if self.feasible(mid) else (mid, hi)
+            lo, hi = (lo, mid) if self.feasible(mid, bound) else (mid, hi)
         return hi
 
 
-def selection_gaps(ref: dict, served_ids: np.ndarray, eng: dict,
-                   n_docs: int) -> tuple[np.ndarray, list]:
-    """-> (gap per query, the reason for each infinite one)."""
+def _query_gap(refs: list, j: int, served: np.ndarray, eng: dict,
+               per: int) -> tuple[float, str | None]:
+    """Query ``j``'s selection gap over shards of ``per`` passages."""
+    shard = served // per
+    if np.any((served < 0) | (shard >= len(refs))):
+        return math.inf, "a served id lies outside the corpus"
+    queries = [_Query({k: v[j] for k, v in ref.items()},
+                      served[shard == s] - s * per, eng, per)
+               for s, ref in enumerate(refs)]
+    reason = next((q.reason for q in queries if q.reason), None)
+    if reason is not None:
+        return math.inf, reason
+    # a passage of a shard's top k that was not served has to lie below
+    # the lowest served Eq. 6; on one shard that is the prefix rule
+    lowest = min(q.min_e_l for q in queries)
+    return max(q.gap(lowest) for q in queries), None
+
+
+def selection_gaps(refs: list, served_ids: np.ndarray, eng: dict,
+                   per: int) -> tuple[np.ndarray, list]:
+    """-> (gap per query, the reason for each infinite one).
+
+    ``refs`` are the shards' readings in shard order
+    (:func:`harness.reference.reference_shards`); ``served_ids`` are
+    global, ``per`` the passages of a shard."""
     gaps, reasons = [], []
     for j in range(len(served_ids)):
-        one = {k: v[j] for k, v in ref.items()}
-        query = _Query(one, served_ids[j], eng, n_docs)
-        gaps.append(query.gap())
-        if query.reason is not None:
-            reasons.append(query.reason)
+        gap, reason = _query_gap(refs, j, np.asarray(served_ids[j]), eng,
+                                 per)
+        gaps.append(gap)
+        if reason is not None:
+            reasons.append(reason)
     return np.array(gaps), reasons
 
 
-def numbers(served_scores: np.ndarray, served_ids: np.ndarray, ref: dict,
-            eng: dict, n_docs: int) -> tuple[dict, list]:
+def rescored(refs: list, served_ids: np.ndarray, per: int) -> np.ndarray:
+    """The reference's Eq. 6 of each served (query, rank), from the shard
+    that holds the passage; NaN for an id outside the corpus."""
+    shard = np.asarray(served_ids) // per
+    out = np.full(np.shape(served_ids), np.nan)
+    for s, ref in enumerate(refs):
+        out = np.where(shard == s, ref["rescored"], out)
+    return out
+
+
+def numbers(served_scores: np.ndarray, served_ids: np.ndarray, refs: list,
+            eng: dict, per: int) -> tuple[dict, list]:
     """-> ({"score_gap": float, "selection_gap": float}, the reasons for
-    infinite selection gaps) over (S, k) arrays and the reference's
-    readings (``harness.reference.reference``)."""
-    score_gap = np.abs(served_scores.astype(np.float64) - ref["rescored"])
-    gaps, reasons = selection_gaps(ref, served_ids, eng, n_docs)
+    infinite selection gaps) over (S, k) arrays and the shards' readings
+    in shard order (``harness.reference.reference_shards``)."""
+    score_gap = np.abs(served_scores.astype(np.float64)
+                       - rescored(refs, served_ids, per))
+    gaps, reasons = selection_gaps(refs, served_ids, eng, per)
     return {
         "score_gap": float(np.max(np.where(np.isnan(score_gap), np.inf,
                                            score_gap))),

@@ -30,6 +30,16 @@ Corpus model (the configuration's ``corpus`` block states every number):
 
 The PLAID fields that the program's index carries are generated at their
 real shapes as zeros: EMVB never reads them.
+
+A configuration whose ``chips`` is above 1 is a deployment block-sharded
+over that many chips: ``n_passages`` is the global count, and shard s holds
+global ids ``[s * n / chips, (s + 1) * n / chips)``. Topics and lengths are
+drawn over the global ids as above; centroids and codebooks are one table
+for every shard; shard s's tokens come from its own key, and its IVF lists
+its passages by local id. The global arrays, with the global IVF that the
+shards' lists make, are kept on the host, and each shard's device copies
+are freed before the next is drawn, so no device holds more than one
+shard's set-up.
 """
 from __future__ import annotations
 
@@ -154,22 +164,31 @@ def build_ivf(codes: jax.Array, n_c: int, multiple: int):
     return ivf, lens
 
 
-def generate_index(cfg: dict, seed: int) -> dict:
-    """The cell's index as a dict of device arrays (the program's index
-    fields, by name) plus ``list_cap``."""
+def _draws(cfg: dict, seed: int):
+    """-> (topics (n_docs,), lengths (n_docs,), centroids, codebooks, the
+    tokens' key) of the global corpus."""
     corpus = cfg["corpus"]
-    n_docs, n_c, d = cfg["n_passages"], cfg["n_centroids"], cfg["d"]
-    cap, m, block = cfg["cap"], cfg["m"], corpus["block"]
+    n_c, d, m = cfg["n_centroids"], cfg["d"], cfg["m"]
     sizes, lens_base = fixed_shapes(cfg)
     rng = host_rng(seed, 1)
     topics = rng.permutation(np.repeat(np.arange(len(sizes)), sizes))
     lens = rng.permutation(lens_base)
-    key = run_key(seed)
-    k_cent, k_cb, k_tok = jax.random.split(key, 3)
-    centroids = _centroids(k_cent, n_c=n_c, d=d, block=block,
+    k_cent, k_cb, k_tok = jax.random.split(run_key(seed), 3)
+    centroids = _centroids(k_cent, n_c=n_c, d=d, block=corpus["block"],
                            spread=float(corpus["block_spread"]))
     codebooks = _codebooks(k_cb, m=m, dsub=d // m,
                            scale=float(corpus["residual_norm"] / math.sqrt(d)))
+    return topics, lens, centroids, codebooks, k_tok
+
+
+def _tokens(key, topics: np.ndarray, lens: np.ndarray, cfg: dict):
+    """-> (codes (n, cap) int32, PQ codes (n, cap, m) uint8, the last
+    chunk's pair) of passages with these topics and lengths, chunk i drawn
+    from ``fold_in(key, i)``. The one-chip build keeps the last chunk alive
+    until it returns, as it always has: ``peak_hbm_gib`` reads set-up's
+    high-water mark, which includes it."""
+    corpus = cfg["corpus"]
+    n_docs = len(lens)
     n_chunks = -(-n_docs // CHUNK_DOCS)
     pad = n_chunks * CHUNK_DOCS - n_docs
     topics = np.concatenate([topics, np.zeros(pad, topics.dtype)])
@@ -177,33 +196,117 @@ def generate_index(cfg: dict, seed: int) -> dict:
     codes, res = [], []
     for i in range(n_chunks):
         sl = slice(i * CHUNK_DOCS, (i + 1) * CHUNK_DOCS)
-        ci, ri = _chunk(jax.random.fold_in(k_tok, i),
+        ci, ri = _chunk(jax.random.fold_in(key, i),
                         jnp.asarray(topics[sl], jnp.int32),
                         jnp.asarray(lens_p[sl], jnp.int32),
-                        cap=cap, n_c=n_c, m=m, block=block,
+                        cap=cfg["cap"], n_c=cfg["n_centroids"], m=cfg["m"],
+                        block=corpus["block"],
                         share=float(corpus["topic_share"]),
                         zipf=float(corpus["topic_zipf"]))
         codes.append(ci)
         res.append(ri)
     codes = jnp.concatenate(codes)[:n_docs]
     res = jnp.concatenate(res)[:n_docs]
-    ivf, ivf_lens = build_ivf(codes, n_c, int(cfg["list_cap_multiple"]))
+    return codes, res, (ci, ri)
+
+
+def _fields(centroids, codes, lens, res, codebooks, ivf, ivf_lens, cfg,
+            xp=jnp) -> dict:
+    """The program's index fields, the PLAID ones as ``xp`` zeros."""
+    n_docs, cap, d = len(lens), cfg["cap"], cfg["d"]
     plaid_b = int(cfg["plaid_b"])
     return {
         "centroids": centroids,
         "codes": codes,
-        "doc_lens": jnp.asarray(lens, jnp.int32),
+        "doc_lens": lens,
         "res_codes": res,
         "pq_codebooks": codebooks,
         "ivf": ivf,
         "ivf_lens": ivf_lens,
-        "plaid_res": jnp.zeros((n_docs, cap, d * plaid_b // 8), jnp.uint8),
-        "plaid_cutoffs": jnp.zeros((2 ** plaid_b - 1,), jnp.float32),
-        "plaid_weights": jnp.zeros((2 ** plaid_b,), jnp.float32),
-        "opq_rotation": jnp.eye(d, dtype=jnp.float32),
-        "pred_words": jnp.zeros((n_docs,), jnp.uint32),
+        "plaid_res": xp.zeros((n_docs, cap, d * plaid_b // 8), np.uint8),
+        "plaid_cutoffs": xp.zeros((2 ** plaid_b - 1,), np.float32),
+        "plaid_weights": xp.zeros((2 ** plaid_b,), np.float32),
+        "opq_rotation": xp.eye(d, dtype=np.float32),
+        "pred_words": xp.zeros((n_docs,), np.uint32),
         "list_cap": int(ivf.shape[1]),
     }
+
+
+def generate_index(cfg: dict, seed: int) -> dict:
+    """The cell's index as a dict of the program's index fields, by name,
+    plus ``list_cap``: device arrays on one chip; with ``chips`` above 1,
+    host arrays of the global index plus ``shards``, each shard's local
+    IVF (``ivf``, ``ivf_lens``, ``list_cap``)."""
+    chips = int(cfg.get("chips", 1))
+    if chips > 1:
+        return _generate_sharded(cfg, seed, chips)
+    topics, lens, centroids, codebooks, k_tok = _draws(cfg, seed)
+    # last_chunk is held until this returns (see _tokens)
+    codes, res, last_chunk = _tokens(k_tok, topics, lens, cfg)
+    ivf, ivf_lens = build_ivf(codes, cfg["n_centroids"],
+                              int(cfg["list_cap_multiple"]))
+    return _fields(centroids, codes, jnp.asarray(lens, jnp.int32), res,
+                   codebooks, ivf, ivf_lens, cfg)
+
+
+def merge_ivfs(shards: list, per: int, multiple: int):
+    """The global IVF of block shards of ``per`` passages: list c is the
+    shards' lists c in shard order, local ids plus the shard's offset,
+    padded with the global count. -> (ivf, ivf_lens) on the host; equal to
+    :func:`build_ivf` over the global codes."""
+    lens = np.sum([np.asarray(sh["ivf_lens"]) for sh in shards], axis=0,
+                  dtype=np.int32)
+    longest = int(lens.max())
+    list_cap = max(8, -(-longest // multiple) * multiple)
+    ivf = np.full((len(lens), list_cap), per * len(shards), np.int32)
+    at = np.zeros(len(lens), np.int64)
+    for s, sh in enumerate(shards):
+        local, ln = np.asarray(sh["ivf"]), np.asarray(sh["ivf_lens"])
+        rows, cols = np.nonzero(np.arange(local.shape[1])[None, :]
+                                < ln[:, None])
+        ivf[rows, at[rows] + cols] = local[rows, cols] + s * per
+        at += ln
+    return ivf, lens
+
+
+def _generate_sharded(cfg: dict, seed: int, chips: int) -> dict:
+    """One shard at a time on JAX's default device, each copied to the
+    host and freed there before the next: the device never holds more
+    than one shard's set-up, which is a one-chip cell's."""
+    n_docs, n_c = cfg["n_passages"], cfg["n_centroids"]
+    per, multiple = n_docs // chips, int(cfg["list_cap_multiple"])
+    topics, lens, centroids, codebooks, k_tok = _draws(cfg, seed)
+    codes = np.empty((n_docs, cfg["cap"]), np.int32)
+    res = np.empty((n_docs, cfg["cap"], cfg["m"]), np.uint8)
+    shards = []
+    for s in range(chips):
+        sl = slice(s * per, (s + 1) * per)
+        codes_s, res_s, _ = _tokens(jax.random.fold_in(k_tok, s),
+                                    topics[sl], lens[sl], cfg)
+        ivf_s, lens_s = build_ivf(codes_s, n_c, multiple)
+        codes[sl], res[sl] = np.asarray(codes_s), np.asarray(res_s)
+        shards.append({"ivf": np.asarray(ivf_s),
+                       "ivf_lens": np.asarray(lens_s),
+                       "list_cap": int(ivf_s.shape[1])})
+        del codes_s, res_s, ivf_s, lens_s
+    ivf, ivf_lens = merge_ivfs(shards, per, multiple)
+    out = _fields(np.asarray(centroids), codes, lens.astype(np.int32), res,
+                  np.asarray(codebooks), ivf, ivf_lens, cfg, xp=np)
+    out["shards"] = shards
+    return out
+
+
+def shard_fields(index: dict, s: int) -> dict:
+    """Shard ``s`` of an index in the one-chip form: its passages with
+    local ids and its local IVF; a one-chip index is its own shard 0."""
+    shards = index.get("shards")
+    if shards is None:
+        return index
+    per = len(index["doc_lens"]) // len(shards)
+    sl = slice(s * per, (s + 1) * per)
+    return dict(index, codes=index["codes"][sl],
+                doc_lens=index["doc_lens"][sl],
+                res_codes=index["res_codes"][sl], **shards[s])
 
 
 @functools.partial(jax.jit, static_argnames=("n_q", "noise"))
@@ -228,14 +331,20 @@ def generate_queries(index: dict, cfg: dict, seed: int, n: int
                      ) -> tuple[np.ndarray, np.ndarray]:
     """-> (queries (n, n_q, d) float32 on the host, planted target ids (n,))."""
     targets = host_rng(seed, 2).integers(0, cfg["n_passages"], n)
-    q = _queries(jax.random.fold_in(run_key(seed), 7),
-                 jnp.asarray(targets, jnp.int32), index["centroids"],
-                 index["codes"], index["doc_lens"], index["res_codes"],
-                 index["pq_codebooks"], n_q=cfg["engine"]["n_q"],
+    rows = (index["codes"], index["doc_lens"], index["res_codes"])
+    at = jnp.asarray(targets, jnp.int32)
+    if "shards" in index:
+        # the targets' rows of their shards, gathered on the host
+        rows = tuple(a[targets] for a in rows)
+        at = jnp.arange(n, dtype=jnp.int32)
+    q = _queries(jax.random.fold_in(run_key(seed), 7), at,
+                 index["centroids"], *rows, index["pq_codebooks"],
+                 n_q=cfg["engine"]["n_q"],
                  noise=float(cfg["corpus"]["query_noise"]))
     return np.array(q, np.float32), targets.astype(np.int64)
 
 
 def index_bytes(index: dict) -> int:
-    """Bytes of every array of the index."""
-    return int(sum(v.nbytes for k, v in index.items() if k != "list_cap"))
+    """Bytes of every array of the program's index."""
+    return int(sum(v.nbytes for v in index.values()
+                   if hasattr(v, "nbytes")))

@@ -25,6 +25,10 @@ pass phase 2.
 ``float32`` for the reference, ``bfloat16`` for the control (the step
 below the configuration's float32). Queries run one after another in one
 compiled loop, so the reference needs the memory of one query only.
+
+A block-sharded index is judged shard by shard (:func:`reference_shards`),
+as each shard of the deployment runs EMVB alone, each shard on its own
+device; :func:`merge_answers` is the deployment's merge of their answers.
 """
 from __future__ import annotations
 
@@ -32,6 +36,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 
 def _or_reduce(words: jax.Array, axis: int) -> jax.Array:
@@ -146,8 +151,9 @@ REF_FIELDS = ("centroids", "codes", "doc_lens", "res_codes", "pq_codebooks",
 
 def reference(index: dict, queries, live, served, eng: dict,
               dtype: str = "float32", pool: int = 8192,
-              e_pool: int = 1024) -> dict:
-    """Run the reference over a batch of queries.
+              e_pool: int = 1024, device=None) -> dict:
+    """Run the reference over a batch of queries, on ``device`` (default:
+    JAX's).
 
     index   : the generated index (only ``REF_FIELDS`` are read)
     queries : (S, n_q, d) float32; live : (S, n_q) bool
@@ -168,8 +174,47 @@ def reference(index: dict, queries, live, served, eng: dict,
     n_docs = index["codes"].shape[0]
     pool = min(int(pool), n_docs)
     sub = {f: index[f] for f in REF_FIELDS}
-    return _reference(sub, jnp.asarray(queries, jnp.float32),
-                      jnp.asarray(live, jnp.bool_),
-                      jnp.asarray(served, jnp.int32), eng=frozen,
-                      dtype=jnp.dtype(dtype).name, pool=pool,
-                      e_pool=min(int(e_pool), pool))
+    args = (jnp.asarray(queries, jnp.float32), jnp.asarray(live, jnp.bool_),
+            jnp.asarray(served, jnp.int32))
+    if device is not None:
+        sub, args = jax.device_put((sub, args), device)
+    return _reference(sub, *args, eng=frozen, dtype=jnp.dtype(dtype).name,
+                      pool=pool, e_pool=min(int(e_pool), pool))
+
+
+def reference_shards(shards: list, queries, live, served, eng: dict,
+                     dtype: str = "float32", pool: int = 8192,
+                     e_pool: int = 1024, devices=None) -> list:
+    """The reference over each block shard of an index, shard s alone, on
+    ``devices[s]`` when given: EMVB as one shard of a sharded deployment
+    runs it, with its own cuts over its own passages.
+
+    shards : per shard, the ``REF_FIELDS`` with local ids; shard s holds
+             global ids ``[s * n, (s + 1) * n)``, n its passage count
+    served : (S, k) global ids; each shard rescores those it holds, by
+             local id, and reads NaN for the rest
+    -> per shard, :func:`reference`'s readings as host arrays (local ids).
+    One shard is the whole index, and then this is :func:`reference`."""
+    served = np.asarray(served, np.int64)
+    outs = []
+    for s, sub in enumerate(shards):
+        per = int(sub["codes"].shape[0])
+        mine = (served >= s * per) & (served < (s + 1) * per)
+        outs.append(reference(sub, queries, live,
+                              np.where(mine, served - s * per, -1), eng,
+                              dtype, pool, e_pool,
+                              None if devices is None else devices[s]))
+    return [{k: np.asarray(v) for k, v in out.items()} for out in outs]
+
+
+def merge_answers(outs: list, per: int, k: int) -> tuple:
+    """The two-level top k of :func:`reference_shards`' readings: each
+    shard's own top k, its ids made global (shard s adds ``s * per``),
+    merged by score, ties to the lower global id.
+    -> (scores (S, k) float32, ids (S, k) int32)."""
+    scores = np.concatenate([o["top"] for o in outs], axis=1)
+    ids = np.concatenate([o["ids"] + s * per for s, o in enumerate(outs)],
+                         axis=1)
+    pos = np.argsort(-scores, axis=1, kind="stable")[:, :k]
+    return (np.take_along_axis(scores, pos, 1).astype(np.float32),
+            np.take_along_axis(ids, pos, 1).astype(np.int32))

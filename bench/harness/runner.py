@@ -3,7 +3,9 @@ result line.
 
 Set-up generates the cell's index and queries on the device from the seed
 (:mod:`harness.indexgen`), builds the program's ``RetrievalService`` over
-it, and warms every batch size the window can flush, through the service.
+it (a cell on several chips: ``repro.launch.serve.make_service`` over a
+mesh of the first ``chips`` devices), and warms every batch size the
+window can flush, through the service.
 The window is an open-loop client (:func:`drive`) that submits each query
 when it is due and times it from then. Afterwards the program's state is
 freed and the plain reference (:mod:`harness.reference`) checks a sample of
@@ -24,9 +26,9 @@ from typing import Callable, Optional
 
 import numpy as np
 
-from . import correctness, indexgen, layers, spec, tracing
+from . import correctness, indexgen, layers, spans, spec, tracing
 from . import traffic as traffic_mod
-from .reference import reference
+from .reference import REF_FIELDS, merge_answers, reference_shards
 
 GIB = float(1 << 30)
 SETTLE_S = 60.0                      # wait for answers past the close
@@ -181,10 +183,11 @@ def _device_info(chips: int) -> dict:
     import jax
 
     devs = jax.devices()
-    peak = 0
-    for d in devs[:chips]:
-        stats = d.memory_stats() or {}
-        peak = max(peak, int(stats.get("peak_bytes_in_use", 0)))
+    peaks = [int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+             for d in devs[:chips]]
+    if chips > 1:
+        log(f"peak bytes in use per chip: {peaks}")
+    peak = max(peaks)
     return {"platform": devs[0].platform, "kind": devs[0].device_kind,
             "count": len(devs), "memory_peak_bytes": peak}
 
@@ -192,7 +195,9 @@ def _device_info(chips: int) -> dict:
 def build_service(index: dict, cfg: dict, traffic: dict, chips: int,
                   plan_wrap: Optional[Callable] = None):
     """The system under test: a ``RetrievalService`` over a one-generation
-    timeline of the generated index, on one chip.
+    timeline of the generated index; on one chip the single-device plan,
+    on ``chips`` above 1 ``repro.launch.serve.make_service`` over a mesh of
+    the first ``chips`` devices, which shards the index itself.
 
     ``plan_wrap(base_plan, index, cfg) -> plan`` replaces the per-
     generation plan; the correctness tests use it to break the timed path
@@ -202,8 +207,6 @@ def build_service(index: dict, cfg: dict, traffic: dict, chips: int,
     from repro.core.store import ShardedTimeline
     from repro.serving import RetrievalService
 
-    if chips != 1:
-        raise ValueError("this harness serves one chip")
     ecfg = EngineConfig(**cfg["engine"])
     pidx = PackedIndex(**{f: index[f] for f in PackedIndex._fields})
     meta = IndexMeta(
@@ -216,31 +219,71 @@ def build_service(index: dict, cfg: dict, traffic: dict, chips: int,
     kwargs = {"max_batch": int(traffic["max_batch"])}
     if traffic.get("max_delay_s") is not None:
         kwargs["max_delay_s"] = float(traffic["max_delay_s"])
-    if plan_wrap is None:
-        return RetrievalService(timeline, ecfg, **kwargs)
+    if chips > 1:
+        import jax
+        from repro.launch.serve import (make_service,
+                                        make_timeline_partial_plans)
 
-    def factory(tl):
-        return [plan_wrap(
-            lambda q, m, f=None, _g=gen, _m=gmeta, _o=off:
-                retrieve_generation_topk(_g, _m, _o, q, ecfg, m,
-                                         doc_filter=f), index, cfg)
-            for gen, gmeta, off in tl]
+        mesh = jax.make_mesh((chips,), ("shard",),
+                             devices=jax.devices()[:chips])
+        if plan_wrap is None:
+            return make_service(mesh, ecfg, timeline, **kwargs)
 
-    return RetrievalService(timeline, ecfg, plan_factory=factory, **kwargs)
+        def base_plans(tl):
+            return make_timeline_partial_plans(mesh, ecfg, tl)
+    else:
+        if plan_wrap is None:
+            return RetrievalService(timeline, ecfg, **kwargs)
+
+        def base_plans(tl):
+            return [lambda q, m, f=None, _g=gen, _m=gmeta, _o=off:
+                    retrieve_generation_topk(_g, _m, _o, q, ecfg, m,
+                                             doc_filter=f)
+                    for gen, gmeta, off in tl]
+
+    return RetrievalService(
+        timeline, ecfg, plan_factory=lambda tl: [
+            plan_wrap(p, index, cfg) for p in base_plans(tl)], **kwargs)
+
+
+def _shards(index: dict, cfg: dict) -> list:
+    return [indexgen.shard_fields(index, s)
+            for s in range(int(cfg.get("chips", 1)))]
+
+
+def _ref_devices(cfg: dict):
+    """One device per shard on several chips; JAX's default on one."""
+    import jax
+
+    chips = int(cfg.get("chips", 1))
+    return jax.devices()[:chips] if chips > 1 else None
 
 
 def control_plan(base_plan, index: dict, cfg: dict):
-    """The control: the reference in bfloat16 in the program's place."""
+    """The control: the reference in bfloat16 in the program's place, per
+    shard and merged on several chips."""
+    import jax
+    import jax.numpy as jnp
     from repro.core.engine import RetrievalResult
 
     k = cfg["engine"]["k"]
     check = cfg["check"]
+    shards = _shards(index, cfg)
+    per = cfg["n_passages"] // len(shards)
+    devices = _ref_devices(cfg)
+    if devices is not None:
+        # each shard's fields go to its device once, not at every flush
+        shards = [jax.device_put({f: sh[f] for f in REF_FIELDS}, dev)
+                  for sh, dev in zip(shards, devices)]
 
     def plan(q, m, f=None):
-        served = np.zeros((q.shape[0], k), np.int32)
-        out = reference(index, q, m, served, cfg["engine"], "bfloat16",
-                        pool=int(check["pool"]), e_pool=int(check["e_pool"]))
-        return RetrievalResult(out["top"], out["ids"])
+        served = np.full((q.shape[0], k), -1, np.int32)
+        outs = reference_shards(shards, q, m, served, cfg["engine"],
+                                "bfloat16", pool=int(check["pool"]),
+                                e_pool=int(check["e_pool"]),
+                                devices=devices)
+        top, ids = merge_answers(outs, per, k)
+        return RetrievalResult(jnp.asarray(top), jnp.asarray(ids))
 
     return plan
 
@@ -255,13 +298,20 @@ E2E = {
 
 
 def _busy(reduced, window) -> tuple[float, float]:
-    """-> (device-busy seconds averaged over the traced devices, window
-    seconds)."""
-    if reduced is None or window is None or not reduced["devices"]:
+    """-> (device-busy seconds averaged over the device planes that ran
+    operations, the chips used, window seconds)."""
+    planes = spans.devices(reduced) if reduced is not None else []
+    if window is None or not planes:
         return 0.0, 0.0
-    busy = [tracing.busy_in(dev, [window])
-            for dev in reduced["devices"].values()]
+    busy = [tracing.busy_in(dev, [window]) for dev in planes]
     return float(np.mean(busy)), float(window[1] - window[0])
+
+
+def _first_plane(reduced):
+    """The first TPU's plane of a reduced trace, by name; None where it is
+    missing or ran no operation."""
+    dev = (reduced or {}).get("devices", {}).get("/device:TPU:0")
+    return dev if dev and dev["ops"] else None
 
 
 def _finite(v):
@@ -320,6 +370,9 @@ def prepare(cell: spec.Cell, seed: int, n: int,
         f"{indexgen.index_bytes(index)} bytes, list_cap {index['list_cap']}, "
         f"mean IVF list length {lens.mean():.3f}, longest {lens.max()}, "
         f"generated in {time.perf_counter() - t:.3f} s")
+    for s, sh in enumerate(index.get("shards", ())):
+        say(f"shard {s}: local list_cap {sh['list_cap']}, longest local "
+            f"list {np.max(sh['ivf_lens'])}")
     max_batch = int(traffic["max_batch"])
     t = time.perf_counter()
     queries, targets = indexgen.generate_queries(index, cfg, seed,
@@ -361,13 +414,15 @@ def answers(tickets: list, answered: np.ndarray, k: int):
 
 def check_reference(st: Setup, cfg: dict, queries: np.ndarray,
                     live: np.ndarray, ids: np.ndarray,
-                    dtype: str = "float32") -> dict:
-    """The reference's readings of ``queries`` (host arrays), with the
-    configuration's pool sizes."""
+                    dtype: str = "float32") -> list:
+    """The reference's readings of ``queries``, per shard (one on one
+    chip; shard s on device s), as host arrays, with the configuration's
+    pool sizes."""
     check = cfg["check"]
-    out = reference(st.index, queries, live, ids, cfg["engine"], dtype,
-                    pool=int(check["pool"]), e_pool=int(check["e_pool"]))
-    return {k: np.asarray(v) for k, v in out.items()}
+    return reference_shards(_shards(st.index, cfg), queries, live, ids,
+                            cfg["engine"], dtype, pool=int(check["pool"]),
+                            e_pool=int(check["e_pool"]),
+                            devices=_ref_devices(cfg))
 
 
 def compare(st: Setup, cfg: dict, seed: int, scores: np.ndarray,
@@ -379,13 +434,14 @@ def compare(st: Setup, cfg: dict, seed: int, scores: np.ndarray,
     t = time.perf_counter()
     if not len(pick):
         return {"score_gap": math.inf, "selection_gap": math.inf}
-    ref = check_reference(st, cfg, st.queries[pick], st.live[pick],
-                          ids[pick])
-    n_cand = ref["n_cand"]
+    refs = check_reference(st, cfg, st.queries[pick], st.live[pick],
+                           ids[pick])
+    n_cand = np.concatenate([r["n_cand"] for r in refs])
     say(f"candidates per query (reference): mean {n_cand.mean():.1f}, "
         f"min {n_cand.min()}, max {n_cand.max()}")
-    nums, reasons = correctness.numbers(scores[pick], ids[pick], ref,
-                                        cfg["engine"], int(cfg["n_passages"]))
+    nums, reasons = correctness.numbers(
+        scores[pick], ids[pick], refs, cfg["engine"],
+        int(cfg["n_passages"]) // len(refs))
     say(f"reference: {len(pick)} queries in {time.perf_counter() - t:.3f} s")
     for why in sorted(set(reasons)):
         say(f"selection not judged for {reasons.count(why)} queries: {why}")
@@ -502,8 +558,8 @@ def run(cell: spec.Cell, seed: int, seconds: int, trace: bool, *,
 
     result = {"correct": bool(ok), "attempted": int(attempted.sum()),
               "failed": failed, "metrics": metrics, "device": device}
-    if trace and reduced is not None and reduced["devices"]:
-        dev0 = next(iter(reduced["devices"].values()))
+    dev0 = _first_plane(reduced)
+    if dev0 is not None:
         result["breakdown"] = {
             "device_ops": tracing.top_ops(dev0),
             "idle_gaps": tracing.idle_gaps(dev0, reduced["host"], window)

@@ -49,7 +49,10 @@ def load_cell(root: str, name: str, bench_dir: Optional[str] = None) -> Cell:
     """Resolve workload ``name`` of ``<root>/BENCHMARK.json``.
 
     ``bench_dir`` is where ``configs/``, ``traffic/`` and ``metrics/`` live
-    (default: the directory of this package's parent)."""
+    (default: the directory of this package's parent). A configuration's
+    ``chips`` (default 1) has to equal the workload's, and its
+    ``n_passages``, the deployment's global count, has to divide into that
+    many block shards."""
     spec = load_json(os.path.join(root, "BENCHMARK.json"))
     bench_dir = bench_dir or os.path.dirname(
         os.path.dirname(os.path.abspath(__file__)))
@@ -63,10 +66,19 @@ def load_cell(root: str, name: str, bench_dir: Optional[str] = None) -> Cell:
         raise SpecError(f"workload {name!r} names unknown config "
                         f"{w['config']!r}")
     config = load_json(os.path.join(root, configs[w["config"]]["file"]))
+    chips = int(w["chips"])
+    if int(config.get("chips", 1)) != chips:
+        raise SpecError(f"workload {name!r} asks for {chips} chips but its "
+                        f"config {w['config']!r} states "
+                        f"{config.get('chips', 1)}")
+    if int(config["n_passages"]) % chips:
+        raise SpecError(f"config {w['config']!r}: n_passages "
+                        f"{config['n_passages']} does not divide into "
+                        f"{chips} shards")
     traffic = load_json(os.path.join(bench_dir, "traffic",
                                      w["traffic"] + ".json"))
     return Cell(
-        name=name, chips=int(w["chips"]), config=config, traffic=traffic,
+        name=name, chips=chips, config=config, traffic=traffic,
         end_to_end=tuple(m for m in spec["end_to_end"] if _applies(m, name)),
         per_layer=tuple(m for m in spec["per_layer"] if _applies(m, name)),
         bench_dir=bench_dir)

@@ -6,17 +6,21 @@ Run from the root of a checkout that holds ``BENCHMARK.json``, ``bench/``
 and the program under ``src/``. The run generates its index and queries on
 the device from ``--seed``, warms every program it will use, measures for
 ``--seconds``, and checks a sample of the answers against the plain
-reference. Earlier lines of standard output say what was generated and
-measured; the last line is one JSON object with ``correct``, ``attempted``,
-``failed``, ``metrics``, ``device`` and ``checks`` (with ``--trace 1`` also
-``breakdown``). The numbers compared are also the last lines of standard
-error. A traced run leaves its reduced trace in ``bench/out/last_trace.json``
+reference. A cell whose ``chips`` is above 1 is served through the
+program's own sharded path, ``repro.launch.serve.make_service`` over a mesh
+of the first ``chips`` devices, and judged per shard. Earlier lines of
+standard output say what was generated and measured; the last line is one
+JSON object with ``correct``, ``attempted``, ``failed``, ``metrics``,
+``device`` and ``checks`` (with ``--trace 1`` also ``breakdown``). The
+numbers compared are also the last lines of standard error. A traced run
+leaves its reduced trace in ``bench/out/last_trace.json``
 (``harness/tracing.py`` describes the format); the compile cache lives in
 ``bench/out/jax_cache`` unless ``JAX_COMPILATION_CACHE_DIR`` is set.
 
 Exit codes: 0 after a result line; 2 when ``BENCHMARK.json``, a file it
-names, or the program is missing; 3 when JAX finds no TPU or fewer chips
-than the cell asks for. Neither of the last two prints a result.
+names, or the program is missing, or a configuration does not shard as its
+cell asks; 3 when JAX finds no TPU or fewer chips than the cell asks for.
+Neither of the last two prints a result.
 """
 import time
 

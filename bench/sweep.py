@@ -9,7 +9,9 @@ the mean flush size, the drain time after the last due query, and
 ``growth``: the median latency of the window's last quarter over that of
 its second quarter. A rate is sustained when growth stays near 1 and the
 drain is short; the knee is the highest such rate. The cell's traffic file
-holds the rate its cells run at, as a number; this tool only finds it.
+holds the rate its cells run at, as a number; this tool only finds it. A
+cell on several chips is set up as ``run.py`` sets it up, through the
+program's sharded service.
 """
 import time
 

@@ -136,7 +136,7 @@ E = np.array([1.0, 4.0, 3.0, 2.0, 0.5, 0.4, 0.3, 6.0, 5.0, 9.0, 9.5, 9.9])
 def test_selection_gap_is_blind_to_the_order_of_ties(tie_order):
     served = _emvb(F, CI, E, tie_order)
     gaps, reasons = correctness.selection_gaps(
-        {k: v[None] for k, v in _readings(F, CI, E).items()}, served[None],
+        [{k: v[None] for k, v in _readings(F, CI, E).items()}], served[None],
         ENG, N_DOCS)
     assert gaps.tolist() == [0.0] and reasons == []
 
@@ -145,11 +145,11 @@ def test_selection_gap_reads_a_wrong_selection():
     # 8 and 2 served: with the F tie 8 taken, phase 3 keeps 0, 8, 2 and
     # either 1 (Eq. 6 4.0, over 2's 3.0 by 1.0) or the tie 7 (Eq. 6 6.0)
     served = np.array([[8, 2]])
-    ref = {k: v[None] for k, v in _readings(F, CI, E).items()}
-    gaps, _ = correctness.selection_gaps(ref, served, ENG, N_DOCS)
+    refs = [{k: v[None] for k, v in _readings(F, CI, E).items()}]
+    gaps, _ = correctness.selection_gaps(refs, served, ENG, N_DOCS)
     assert gaps[0] == pytest.approx(1.0, rel=0.02)
     # a passage that cannot pass the pre-filter, and a passage named twice
     for bad in ([[9, 7]], [[7, 7]]):
-        gaps, reasons = correctness.selection_gaps(ref, np.array(bad), ENG,
-                                                   N_DOCS)
+        gaps, reasons = correctness.selection_gaps(refs, np.array(bad),
+                                                   ENG, N_DOCS)
         assert gaps.tolist() == [np.inf] and len(reasons) == 1
