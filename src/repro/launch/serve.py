@@ -4,6 +4,10 @@ Each device owns a doc shard with a *local* IVF, runs the full four-phase
 pipeline locally for the whole query batch, and the per-shard top-k are
 merged with one all-gather + re-top-k (two-level top-k): collective traffic
 is O(B * k) instead of O(corpus gathers). Runs on any mesh size.
+
+The jitted shard_map program is named ``_shardmap_retrieve``, so a device
+trace tells its launches apart from the single-device ``_retrieve_jit``;
+its merge runs under ``jax.named_scope("launch.merge")``.
 """
 from __future__ import annotations
 
@@ -43,12 +47,13 @@ def _local_retrieve(index_local: PackedIndex, queries: jax.Array,
     global_ids = local.doc_ids + shard_id * n_local
 
     # two-level top-k: all-gather each shard's k, rerank
-    sc = jax.lax.all_gather(local.scores, axes, axis=0, tiled=False)
-    gi = jax.lax.all_gather(global_ids, axes, axis=0, tiled=False)
-    sc = jnp.moveaxis(sc, 0, 1).reshape(queries.shape[0], -1)   # (B, S*k)
-    gi = jnp.moveaxis(gi, 0, 1).reshape(queries.shape[0], -1)
-    top_sc, pos = jax.lax.top_k(sc, cfg.k)
-    return RetrievalResult(top_sc, jnp.take_along_axis(gi, pos, axis=1))
+    with jax.named_scope("launch.merge"):
+        sc = jax.lax.all_gather(local.scores, axes, axis=0, tiled=False)
+        gi = jax.lax.all_gather(global_ids, axes, axis=0, tiled=False)
+        sc = jnp.moveaxis(sc, 0, 1).reshape(queries.shape[0], -1)  # (B, S*k)
+        gi = jnp.moveaxis(gi, 0, 1).reshape(queries.shape[0], -1)
+        top_sc, pos = jax.lax.top_k(sc, cfg.k)
+        return RetrievalResult(top_sc, jnp.take_along_axis(gi, pos, axis=1))
 
 
 def make_shardmap_retriever(mesh: Mesh, cfg: EngineConfig):
@@ -84,11 +89,11 @@ def make_shardmap_retriever(mesh: Mesh, cfg: EngineConfig):
             @functools.partial(jax.jit)
             @functools.partial(jax.shard_map, mesh=mesh, in_specs=in_specs,
                                out_specs=out_specs, check_vma=False)
-            def step(index_stacked, queries, q_masks):
+            def _shardmap_retrieve(index_stacked, queries, q_masks):
                 index_local = jax.tree.map(lambda x: x[0], index_stacked)
                 return _local_retrieve(index_local, queries, q_masks,
                                        fcfg, axes)
-            steps[fcfg] = step
+            steps[fcfg] = _shardmap_retrieve
         return steps[fcfg]
 
     def run(index_stacked, queries, q_masks=None, *, doc_filter=None):
@@ -167,8 +172,9 @@ def make_timeline_partial_plans(mesh: Mesh, cfg: EngineConfig, timeline, *,
             optional compiled FilterPlan applied on every shard."""
             # dispatch-only span (jax is async); generation attr is the
             # plan's position in the timeline it was built from
+            batch = _as_query_batch(queries).q.shape[0]
             with trace.span("launch.shard_plan", generation=_g,
-                            shards=n_shards):
+                            shards=n_shards, batch=batch):
                 r = _retriever(_stacked, queries, q_masks,
                                doc_filter=doc_filter)
                 return RetrievalResult(r.scores, r.doc_ids + jnp.int32(_off))
@@ -237,7 +243,18 @@ def shard_index(index: PackedIndex, n_shards: int, *,
     With ``mesh`` (of ``n_shards`` devices) every stacked leaf is placed
     with the shard_map plan's ``NamedSharding``, so device s holds shard s
     and the plan moves no index bytes; without it the leaves go to the
-    default device."""
+    default device.
+
+    The split is the set-up span ``launch.shard_index``: its ``shards``,
+    ``list_cap``, ``longest_local_list`` (the longest list on any shard)
+    and ``dropped`` (IVF entries lost to overflow) attributes."""
+    with trace.span("launch.shard_index", shards=n_shards) as sp:
+        return _shard_index(index, n_shards, mesh, sp)
+
+
+def _shard_index(index: PackedIndex, n_shards: int, mesh: Mesh | None,
+                 sp) -> PackedIndex:
+    """``shard_index``'s body; records its attributes on ``sp``."""
     import warnings
 
     import numpy as np
@@ -281,7 +298,9 @@ def shard_index(index: PackedIndex, n_shards: int, *,
             f"list_cap={list_cap}; {n_dropped} doc-id entries dropped — "
             "those docs are unreachable through the overflowed centroid on "
             "their shard. Rebuild with a larger list_cap.",
-            stacklevel=2)
+            stacklevel=3)
+    sp.set(list_cap=int(list_cap), longest_local_list=int(local_lens.max()),
+           dropped=int(n_dropped))
 
     def rep(x):
         return np.broadcast_to(np.asarray(x), (n_shards, *np.shape(x))).copy()

@@ -1,6 +1,20 @@
 """Distributed serving: the shard_map plan equals the single-device engine,
 shard_index's local IVFs are consistent with the global one, and the
-multi-generation timeline plan equals the single-device merge path."""
+multi-generation timeline plan equals the single-device merge path. The
+plan's program is named ``_shardmap_retrieve`` and its set-up and dispatch
+spans carry their attributes; on four devices its answers are pinned bit
+for bit.
+
+Run as a script (``python test_serve_distributed.py``) this file is the
+four-device subprocess: it prints one JSON line per case with digests of
+the sharded answer's ids and scores.
+"""
+import hashlib
+import json
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -176,3 +190,135 @@ def test_per_shard_topk_merge_recovers_global(small_corpus, small_index):
         assert set(top_ids[b].tolist()) == set(want.tolist())
         np.testing.assert_allclose(np.sort(top_sc[b]),
                                    np.sort(exact[want]), rtol=1e-4)
+
+
+def test_shardmap_program_carries_its_name(small_corpus, small_index):
+    """A device trace finds the plan's launches by the program's name, apart
+    from the single-device ``_retrieve_jit``; its merge is named too."""
+    idx, _ = small_index
+    q = jnp.asarray(small_corpus.queries[:2])
+    mesh = jax.make_mesh((1,), ("shard",))
+    run = make_shardmap_retriever(mesh, CFG)
+    stacked = shard_index(idx, 1, mesh=mesh)
+    names = [e.params["name"] for e in jax.make_jaxpr(run)(stacked, q).eqns
+             if e.primitive.name in ("pjit", "jit")]
+    text = jax.jit(run).lower(stacked, q).as_text(debug_info=True)
+    assert names == ["_shardmap_retrieve"]
+    assert "_retrieve_jit" not in text
+    merge = [ln for ln in text.splitlines() if "launch.merge" in ln]
+    assert any("all_gather" in ln for ln in merge), merge[:5]
+    assert any("top_k" in ln or "topk" in ln for ln in merge), merge[:5]
+
+
+def test_shard_plan_span_records_batch(small_corpus, small_index):
+    from repro.core import ShardedTimeline
+    from repro.launch.serve import make_timeline_partial_plans
+    from repro.obs import trace
+
+    idx, meta = small_index
+    mesh = jax.make_mesh((1,), ("shard",))
+    with trace.tracing() as tracer:
+        (plan,) = make_timeline_partial_plans(
+            mesh, CFG, ShardedTimeline.of((idx, meta)))
+        plan(jnp.asarray(small_corpus.queries[:3]))
+    (sp,) = [s for s in tracer.finished() if s["name"] == "launch.shard_plan"]
+    assert sp["attrs"] == {"generation": 0, "shards": 1, "batch": 3}
+
+
+def test_shard_index_span_records_its_lists(small_index):
+    from repro.obs import trace
+
+    idx, _ = small_index
+    with trace.tracing() as tracer:
+        st = shard_index(idx, 4)
+    (sp,) = [s for s in tracer.finished() if s["name"] == "launch.shard_index"]
+    lens = np.asarray(st.ivf_lens)
+    assert sp["attrs"] == {"shards": 4, "list_cap": int(idx.ivf.shape[1]),
+                           "longest_local_list": int(lens.max()),
+                           "dropped": 0}
+    assert 0 < lens.max() <= idx.ivf.shape[1]
+    # every global IVF entry lands in one local list: nothing dropped
+    assert lens.sum() == np.asarray(idx.ivf_lens).sum()
+
+
+# sha256 of the ids' and the scores' bytes of the four-device answer, per
+# case, as the plan gave them before its program was named and its merge
+# scoped (computed in this file's subprocess, whose XLA flags are fixed).
+PINNED = {
+    "plain": (
+        "5699db52bef22890c9f5db1093cb44f568c26ae4aa6711bf9466ae9c2b8886aa",
+        "2415618fda8046c972b15feaec4792087e94a0d03735b185ac291d907854ce8d"),
+    "masked": (
+        "2a491db5ba0824dda9ba4d2bcdd054dfcdd7de083445adf1a96d7479116ca341",
+        "897ef45a8431ca49c05e00fa6eb4c4bfd53d64cadc06ce65384c83d9b4e826bc"),
+    "filtered": (
+        "78c10ed11eac322b45b97046862ea5a96e4b5fca8ea8b115ff632b3a7f583fee",
+        "6f1ddbf79e868fba1e5f98519615541bd60cca4e66b13e0b46fa53d32d201784"),
+    "masked+filtered": (
+        "3e1497a88db938c50f5f6c6b6ff50c2ec3a4a87f8093a44eeff906ac9a6f03f1",
+        "f122b4e626ecd79ab49f7b4386c3b1955a18f6baa6c967f65390c205561a6c0c"),
+}
+
+
+def main() -> int:
+    """The four-device subprocess: one JSON line per case."""
+    from repro.core import bitvector as bv
+    from repro.core.index import build_index
+
+    assert len(jax.devices()) == 4, jax.devices()
+    n_docs, cap, d = 256, 12, 16
+    embs = np.asarray(jax.random.normal(jax.random.PRNGKey(0),
+                                        (n_docs, cap, d)))
+    lens = np.random.default_rng(1).integers(4, cap + 1, n_docs)
+    lens = lens.astype(np.int32)
+    rng = np.random.default_rng(0)
+    preds = {"lang_en": rng.random(n_docs) < 0.7,
+             "recent": rng.random(n_docs) < 0.5}
+    idx, meta = build_index(jax.random.PRNGKey(0), embs, lens,
+                            n_centroids=32, predicates=preds)
+    q = jnp.asarray(jax.random.normal(jax.random.PRNGKey(2), (3, 8, d)))
+    mask = jnp.asarray(np.arange(8)[None, :] < np.array([[8], [5], [2]]))
+    plan = bv.compile_filter(bv.Pred("recent") & ~bv.Pred("lang_en"),
+                             meta.pred_names)
+    cfg = EngineConfig(n_q=8, nprobe=4, th=0.2, th_r=0.3, n_filter=32,
+                       n_docs=16, k=8)
+    mesh = jax.make_mesh((4,), ("shard",))
+    run = make_shardmap_retriever(mesh, cfg)
+    stacked = shard_index(idx, 4, mesh=mesh)
+    cases = {"plain": (None, None), "masked": (mask, None),
+             "filtered": (None, plan), "masked+filtered": (mask, plan)}
+    for case, (m, f) in cases.items():
+        out = run(stacked, q, m, doc_filter=f)
+        ids, scores = np.asarray(out.doc_ids), np.asarray(out.scores)
+        print(json.dumps({
+            "case": case, "ids": hashlib.sha256(ids.tobytes()).hexdigest(),
+            "scores": hashlib.sha256(scores.tobytes()).hexdigest(),
+            "found": int((ids >= 0).sum())}), flush=True)
+    return 0
+
+
+@pytest.fixture(scope="module")
+def four_device_answers():
+    src = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "src")
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               XLA_FLAGS="--xla_force_host_platform_device_count=4 "
+                         "--xla_backend_optimization_level=0")
+    env["PYTHONPATH"] = os.pathsep.join(
+        [src] + env.get("PYTHONPATH", "").split(os.pathsep))
+    p = subprocess.run([sys.executable, os.path.abspath(__file__)], env=env,
+                       capture_output=True, text=True, timeout=240)
+    assert p.returncode == 0, p.stderr[-4000:]
+    return {x["case"]: x for x in map(json.loads, p.stdout.splitlines())}
+
+
+@pytest.mark.parametrize("case", sorted(PINNED))
+def test_four_device_answers_equal_the_unnamed_plan(four_device_answers,
+                                                    case):
+    got = four_device_answers[case]
+    assert got["found"] > 0
+    assert (got["ids"], got["scores"]) == PINNED[case]
+
+
+if __name__ == "__main__":
+    sys.exit(main())
